@@ -37,7 +37,6 @@ from typing import Callable, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.dropout_plan import BatchShard
@@ -124,10 +123,10 @@ def sharded_value_and_grad(loss_fn: Callable, weight_fn: Callable,
 
     def vag(params, batch, step, key):
         check_batch_divisible(batch, n)
-        f = shard_map(local, mesh=mesh,
-                      in_specs=(P(), batch_pspecs(batch, axes), P(), P()),
-                      out_specs=(P(), P()),
-                      check_rep=False)
+        f = jax.shard_map(local, mesh=mesh,
+                          in_specs=(P(), batch_pspecs(batch, axes), P(), P()),
+                          out_specs=(P(), P()),
+                          check_vma=False)
         return f(params, batch, step, key)
 
     return vag

@@ -187,15 +187,11 @@ def shard_put(tree, axes_tree, rules: ShardingRules, mesh: Mesh):
 def shard_act(x: jax.Array, axes: Sequence[Optional[str]],
               rules: Optional[ShardingRules]) -> jax.Array:
     """with_sharding_constraint by logical names; no-op when rules is None
-    (CPU tests) or when we're not inside a mesh context."""
+    (CPU tests) or when no mesh is set (``jax.set_mesh``)."""
     if rules is None:
         return x
-    try:
-        from jax._src.mesh import thread_resources
-        mesh = thread_resources.env.physical_mesh
-        if mesh.empty:
-            return x
-    except Exception:
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     spec = logical_to_pspec(axes, rules, x.shape, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
+    return jax.lax.with_sharding_constraint(x, spec)

@@ -61,12 +61,11 @@ Oracles: this module is tested against the plain-``lax.scan`` references
 ``kernels/ref.py::slstm_scan_ref`` (via kernels/slstm_scan.py), with
 grads checked against autodiff of those references.
 
-The pallas path targets TPU and auto-falls back to interpret mode off TPU
-(correct, not fast); ``impl="xla"`` is the CPU production path. VMEM
-budget and tile-alignment notes from PR 3 carry over per head: u
-(H, dh, G) must fit on-core beside the (B, H, ·) working set, and on real
-TPU the gathered ``block_size`` wants lane alignment (128) — interpret
-mode validates any size.
+The pallas path compiles for the TPU and runs in interpret mode elsewhere
+(correct, not fast); ``impl="xla"`` is the CPU production path. u
+(H, dh, G) must fit in VMEM beside the (B, H, ·) working set (see
+kernels/lstm_scan.py). Structured mode takes any ``block_size``: see
+"Keep-block row layout" below for how its gathers stay tile-aligned.
 """
 from __future__ import annotations
 
@@ -80,6 +79,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret, scan_compiler_params
 
 
 @dataclasses.dataclass(frozen=True)
@@ -129,6 +130,91 @@ def _unit_ids_table(kb, block_size):
 
 
 # ---------------------------------------------------------------------------
+# Keep-block row layout for the structured Pallas path.
+#
+# Mosaic slices a ref along its rows (sublanes) at a dynamic offset only
+# when it can prove the offset a multiple of the row tile (8 rows of f32,
+# 16 of bf16), and a value along its lanes only at multiples of 128. A
+# keep-block of ``block_size`` units therefore starts at row
+# ``bid * padded`` of a ref whose every block is zero-padded to
+# ``padded`` rows, a multiple of the tile: the wrapper pads the weight
+# (rows = hidden units) that way, and the kernel stages the hidden state
+# transposed, hidden units on rows, in a scratch of the same layout. Only
+# the ``block_size`` real rows of a block are ever read, so the padding
+# rows never enter a product, and block ids (the (T, nk) table) keep
+# their meaning.
+# ---------------------------------------------------------------------------
+
+
+def _padded_block(block_size, dtype):
+    """``block_size`` rounded up to the row tile of ``dtype`` (>= f32's 8)."""
+    tile = 8 * max(1, 4 // jnp.dtype(dtype).itemsize)
+    return -(-block_size // tile) * tile
+
+
+def _pad_blocks(w, block_size, padded):
+    """(..., nb*bs, N) -> (..., nb*padded, N): zero rows after each block."""
+    if padded == block_size:
+        return w
+    *lead, rows, n = w.shape
+    nb = rows // block_size
+    w = w.reshape(*lead, nb, block_size, n)
+    w = jnp.pad(w, [(0, 0)] * len(lead) + [(0, 0), (0, padded - block_size),
+                                            (0, 0)])
+    return w.reshape(*lead, nb * padded, n)
+
+
+def _unpad_blocks(w, block_size, padded):
+    """Inverse of ``_pad_blocks``."""
+    if padded == block_size:
+        return w
+    *lead, rows, n = w.shape
+    nb = rows // padded
+    w = w.reshape(*lead, nb, padded, n)[..., :block_size, :]
+    return w.reshape(*lead, nb * block_size, n)
+
+
+def _block_ds(bid, block_size, padded):
+    """Row slice of the ``block_size`` real rows of keep-block ``bid``."""
+    return pl.ds(pl.multiple_of(bid * padded, padded), block_size)
+
+
+def _block_rows(ref, bid, block_size, padded, lead=()):
+    """The real rows of keep-block ``bid`` of a block-padded ref; ``lead``
+    indexes the ref's leading axes (e.g. the head)."""
+    return ref[(*lead, _block_ds(bid, block_size, padded), slice(None))]
+
+
+def _stage_blocks(x, ref, block_size, padded, lead=()):
+    """Write x (B, W) transposed into ref: block j at rows j*padded."""
+    xt = x.T
+    for j in range(x.shape[1] // block_size):
+        rows = slice(j * padded, j * padded + block_size)
+        ref[(*lead, rows, slice(None))] = \
+            xt[j * block_size:(j + 1) * block_size]
+
+
+def _unstage_blocks(ref, width, block_size, padded, lead=()):
+    """Read a block-staged ref back as a (B, width) value."""
+    return jnp.concatenate(
+        [ref[(*lead, slice(j * padded, j * padded + block_size),
+              slice(None))]
+         for j in range(width // block_size)], axis=0).T
+
+
+def _dot_tn(a, b):
+    """a.T @ b in f32, contracting the leading (row) axis of both."""
+    return jax.lax.dot_general(a, b, (((0,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _dot_nt(a, b):
+    """a @ b.T in f32, contracting the trailing (lane) axis of both."""
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
 # Pallas kernels. Grid = (T,): one grid step per time step, carry in scratch.
 # Variadic refs (the cell's state count is a parameter) are unpacked by
 # position: [scalar ids, scalar lens | inputs | outputs | scratch]. The
@@ -139,21 +225,20 @@ def _unit_ids_table(kb, block_size):
 # ---------------------------------------------------------------------------
 
 
-def _recurrent_fwd(gates, h_prev, u_ref, ids_ref, m_ref, t, *,
-                   heads, nk, block_size, scale, mode, fixed):
+def _recurrent_fwd(gates, h_prev, u_ref, ids_ref, m_ref, stage, t, *,
+                   heads, nk, block_size, padded, scale, mode, fixed):
     """Add the per-head recurrent matmul h_{t-1} @ U into ``gates``."""
     bs = block_size
     out = []
     if mode == "structured":
         for hd in range(heads):
-            hh = h_prev[:, hd]
+            _stage_blocks(h_prev[:, hd], stage, bs, padded, (hd,))
             acc = jnp.zeros_like(gates[:, hd])
             for k in range(nk):                 # static unroll: exact-k masks
                 bid = ids_ref[0 if fixed else t, k]
-                hb = jax.lax.dynamic_slice(hh, (0, bid * bs),
-                                           (hh.shape[0], bs))
-                ub = u_ref[hd, pl.ds(bid * bs, bs), :].astype(jnp.float32)
-                acc += jnp.dot(hb, ub, preferred_element_type=jnp.float32)
+                hb = _block_rows(stage, bid, bs, padded, (hd,))   # (bs, B)
+                ub = _block_rows(u_ref, bid, bs, padded, (hd,))   # (bs, G)
+                acc += _dot_tn(hb, ub.astype(jnp.float32))
             out.append(gates[:, hd] + acc * scale)
     elif mode == "dense":
         hm = h_prev * m_ref[0].astype(jnp.float32) * scale
@@ -170,7 +255,8 @@ def _recurrent_fwd(gates, h_prev, u_ref, ids_ref, m_ref, t, *,
 
 
 def _fwd_kernel(*args, cell: CellSpec, heads: int, nk: int, block_size: int,
-                scale: float, mode: str, fixed: bool, ragged: bool):
+                padded: int, scale: float, mode: str, fixed: bool,
+                ragged: bool):
     ns = cell.num_states
     ids_ref, lens_ref = args[0], args[1]
     gx_ref, u_ref, h0_ref = args[2:5]
@@ -181,6 +267,7 @@ def _fwd_kernel(*args, cell: CellSpec, heads: int, nk: int, block_size: int,
     stseq_refs = args[8 + ns:8 + 2 * ns]
     h_s = args[8 + 2 * ns]
     st_s = args[9 + 2 * ns:9 + 3 * ns]
+    stage = args[9 + 3 * ns] if mode == "structured" else None
 
     t = pl.program_id(0)
 
@@ -192,9 +279,9 @@ def _fwd_kernel(*args, cell: CellSpec, heads: int, nk: int, block_size: int,
 
     h_prev = h_s[...]
     gates = _recurrent_fwd(gx_ref[0].astype(jnp.float32), h_prev, u_ref,
-                           ids_ref, m_ref, t, heads=heads, nk=nk,
-                           block_size=block_size, scale=scale, mode=mode,
-                           fixed=fixed)
+                           ids_ref, m_ref, stage, t, heads=heads, nk=nk,
+                           block_size=block_size, padded=padded, scale=scale,
+                           mode=mode, fixed=fixed)
     st_prev = tuple(s[...] for s in st_s)
     h_new, st_new = cell.pointwise_fwd(gates, st_prev)
     if ragged:
@@ -213,8 +300,8 @@ def _fwd_kernel(*args, cell: CellSpec, heads: int, nk: int, block_size: int,
 
 
 def _bwd_kernel(*args, cell: CellSpec, heads: int, n_steps: int, nk: int,
-                block_size: int, scale: float, mode: str, fixed: bool,
-                ragged: bool):
+                block_size: int, padded: int, scale: float, mode: str,
+                fixed: bool, ragged: bool):
     """Reverse-time step: grid step t processes time step r = T-1-t.
 
     All time-indexed refs arrive through r-indexed BlockSpecs; dU accumulates
@@ -236,6 +323,8 @@ def _bwd_kernel(*args, cell: CellSpec, heads: int, n_steps: int, nk: int,
     dh_s = args[10 + 4 * ns]
     dst_s = args[11 + 4 * ns:11 + 5 * ns]
     du_s = args[11 + 5 * ns]
+    if mode == "structured":
+        h_stage, dh_stage = args[12 + 5 * ns:14 + 5 * ns]
 
     t = pl.program_id(0)
     r = n_steps - 1 - t                      # the time step being processed
@@ -265,27 +354,27 @@ def _bwd_kernel(*args, cell: CellSpec, heads: int, n_steps: int, nk: int,
                                           dst_c)
     dgx_ref[0] = dgates.astype(dgx_ref.dtype)
 
-    B = dh.shape[0]
     bs = block_size
     dhp = []
     if mode == "structured":
+        dh_width = dh.shape[-1]
         for hd in range(heads):
             dgh = dgates[:, hd]
-            hh = h_prev[:, hd]
-            dh_h = jnp.zeros_like(dh[:, hd])
+            _stage_blocks(h_prev[:, hd], h_stage, bs, padded, (hd,))
+            dh_stage[hd] = jnp.zeros(dh_stage.shape[1:], jnp.float32)
             for k in range(nk):                 # static unroll
                 bid = ids_ref[0 if fixed else r, k]
-                ub = u_ref[hd, pl.ds(bid * bs, bs), :].astype(jnp.float32)
+                rows = _block_ds(bid, bs, padded)
+                ub = _block_rows(u_ref, bid, bs, padded, (hd,)
+                                 ).astype(jnp.float32)            # (bs, G)
                 # BP: only the kept columns of dh_{t-1} get a contribution.
-                dhb = jnp.dot(dgh, ub.T,
-                              preferred_element_type=jnp.float32) * scale
-                dh_h = jax.lax.dynamic_update_slice(dh_h, dhb, (0, bid * bs))
+                dh_stage[hd, rows, :] = _dot_nt(ub, dgh) * scale  # (bs, B)
                 # WG: compact (bs, G) product accumulated into the kept rows.
-                hb = jax.lax.dynamic_slice(hh, (0, bid * bs), (B, bs))
-                cur = du_s[hd, pl.ds(bid * bs, bs), :]
-                du_s[hd, pl.ds(bid * bs, bs), :] = cur + jnp.dot(
-                    hb.T, dgh, preferred_element_type=jnp.float32) * scale
-            dhp.append(dh_h)
+                hb = _block_rows(h_stage, bid, bs, padded, (hd,))  # (bs, B)
+                du_s[hd, rows, :] = du_s[hd, rows, :] + jnp.dot(
+                    hb, dgh, preferred_element_type=jnp.float32) * scale
+            dhp.append(_unstage_blocks(dh_stage, dh_width, bs, padded,
+                                       (hd,)))
     elif mode == "dense":
         m = m_ref[0].astype(jnp.float32)         # (B, 1|H, dh)
         for hd in range(heads):
@@ -334,6 +423,21 @@ def _mask_inputs(mask, dtype, fixed, rev=None):
     return mask, spec
 
 
+def _structured_layout(mode, u, batch, block_size):
+    """(u as the kernel reads it, padded block rows, staging scratch).
+
+    Structured mode pads U's keep-blocks to the row tile and stages the
+    hidden state in a (H, rows, B) f32 scratch of the same layout (see
+    "Keep-block row layout" above); the other modes use U as given.
+    """
+    if mode != "structured":
+        return u, block_size, []
+    padded = _padded_block(block_size, u.dtype)
+    u_in = _pad_blocks(u, block_size, padded)
+    heads, rows = u_in.shape[:2]
+    return u_in, padded, [pltpu.VMEM((heads, rows, batch), jnp.float32)]
+
+
 def _pallas_fwd(cell, gx, u, h0, states0, kb, mask, lengths, *, block_size,
                 scale, interpret):
     T, B, H, G = gx.shape
@@ -346,12 +450,13 @@ def _pallas_fwd(cell, gx, u, h0, states0, kb, mask, lengths, *, block_size,
     ids = kb if mode == "structured" else _dummy_ids()
     lens = lengths.astype(jnp.int32) if ragged else _dummy_lens()
     m_in, m_spec = _mask_inputs(mask, gx.dtype, fixed)
+    u_in, padded, stage = _structured_layout(mode, u, B, block_size)
     const3 = pl.BlockSpec((B, H, dh), lambda t, *_: (0, 0, 0))
     seq3 = pl.BlockSpec((1, B, H, dh), lambda t, *_: (t, 0, 0, 0))
     odt = h0.dtype
     kernel = functools.partial(
         _fwd_kernel, cell=cell, heads=H, nk=nk, block_size=block_size,
-        scale=scale, mode=mode, fixed=fixed, ragged=ragged)
+        padded=padded, scale=scale, mode=mode, fixed=fixed, ragged=ragged)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -359,7 +464,7 @@ def _pallas_fwd(cell, gx, u, h0, states0, kb, mask, lengths, *, block_size,
             grid=(T,),
             in_specs=[
                 pl.BlockSpec((1, B, H, G), lambda t, *_: (t, 0, 0, 0)),
-                pl.BlockSpec((H, dh, G), lambda t, *_: (0, 0, 0)),  # U resident
+                pl.BlockSpec(u_in.shape, lambda t, *_: (0, 0, 0)),  # U resident
                 const3,
                 *([const3] * ns),
                 m_spec,
@@ -369,14 +474,16 @@ def _pallas_fwd(cell, gx, u, h0, states0, kb, mask, lengths, *, block_size,
                 pl.BlockSpec((1, B, H, G), lambda t, *_: (t, 0, 0, 0)),
                 *([seq3] * ns),
             ],
-            scratch_shapes=[pltpu.VMEM((B, H, dh), jnp.float32)] * (1 + ns),
+            scratch_shapes=[pltpu.VMEM((B, H, dh), jnp.float32)] * (1 + ns)
+            + stage,
         ),
         out_shape=[jax.ShapeDtypeStruct((T, B, H, dh), odt),
                    jax.ShapeDtypeStruct((T, B, H, G), gx.dtype),
                    *[jax.ShapeDtypeStruct((T, B, H, dh), s.dtype)
                      for s in states0]],
+        compiler_params=scan_compiler_params(),
         interpret=interpret,
-    )(ids, lens, gx, u, h0, *states0, m_in)
+    )(ids, lens, gx, u_in, h0, *states0, m_in)
     hs, gates = outs[0], outs[1]
     return hs, gates, tuple(outs[2:])
 
@@ -394,13 +501,14 @@ def _pallas_bwd(cell, dy, dstT, gates, st_seqs, st_prev_seqs, h_prev_seq, u,
     lens = lengths.astype(jnp.int32) if ragged else _dummy_lens()
     rev = lambda t, *_: (T - 1 - t, 0, 0, 0)         # reverse-time index map
     m_in, m_spec = _mask_inputs(mask, gates.dtype, fixed, rev=rev)
+    u_in, padded, stage = _structured_layout(mode, u, B, block_size)
     const3 = pl.BlockSpec((B, H, dh), lambda t, *_: (0, 0, 0))
     rev3 = pl.BlockSpec((1, B, H, dh), rev)
     odt = dy.dtype
     kernel = functools.partial(
         _bwd_kernel, cell=cell, heads=H, n_steps=T, nk=nk,
-        block_size=block_size, scale=scale, mode=mode, fixed=fixed,
-        ragged=ragged)
+        block_size=block_size, padded=padded, scale=scale, mode=mode,
+        fixed=fixed, ragged=ragged)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -412,27 +520,29 @@ def _pallas_bwd(cell, dy, dstT, gates, st_seqs, st_prev_seqs, h_prev_seq, u,
                 *([rev3] * ns),                             # states at t
                 *([rev3] * ns),                             # states at t-1
                 rev3,                                       # h_{t-1}
-                pl.BlockSpec((H, dh, G), lambda t, *_: (0, 0, 0)),  # U
+                pl.BlockSpec(u_in.shape, lambda t, *_: (0, 0, 0)),  # U
                 m_spec,
                 *([const3] * ns),                           # d(state_T)
             ],
             out_specs=[
                 pl.BlockSpec((1, B, H, G), rev),            # dgx
-                pl.BlockSpec((H, dh, G), lambda t, *_: (0, 0, 0)),  # dU
+                pl.BlockSpec(u_in.shape, lambda t, *_: (0, 0, 0)),  # dU
                 const3,                                     # dh0
                 *([const3] * ns),                           # d(state_0)
             ],
             scratch_shapes=[pltpu.VMEM((B, H, dh), jnp.float32)] * (1 + ns)
-            + [pltpu.VMEM((H, dh, G), jnp.float32)],
+            + [pltpu.VMEM(u_in.shape, jnp.float32)] + stage * 2,
         ),
         out_shape=[jax.ShapeDtypeStruct((T, B, H, G), odt),
-                   jax.ShapeDtypeStruct((H, dh, G), u.dtype),
+                   jax.ShapeDtypeStruct(u_in.shape, u.dtype),
                    jax.ShapeDtypeStruct((B, H, dh), odt),
                    *[jax.ShapeDtypeStruct((B, H, dh), odt)] * ns],
+        compiler_params=scan_compiler_params(),
         interpret=interpret,
-    )(ids, lens, dy, gates, *st_seqs, *st_prev_seqs, h_prev_seq, u, m_in,
-      *dstT)
-    dgx, du, dh0 = outs[0], outs[1], outs[2]
+    )(ids, lens, dy, gates, *st_seqs, *st_prev_seqs, h_prev_seq, u_in,
+      m_in, *dstT)
+    dgx, dh0 = outs[0], outs[2]
+    du = _unpad_blocks(outs[1], block_size, padded)
     return dgx, du, dh0, tuple(outs[3:])
 
 
@@ -677,10 +787,8 @@ def cell_scan(gx: jax.Array, u: jax.Array, h0: jax.Array,
     """
     if keep_blocks is not None and dense_mask is not None:
         raise ValueError("give at most one of keep_blocks / dense_mask")
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     hs, h_fin, st_fin = _cell_scan(cell, int(block_size), float(scale),
-                                   impl, bool(interpret),
+                                   impl, resolve_interpret(interpret),
                                    gx, u, h0, tuple(states0),
                                    keep_blocks, dense_mask, lengths)
     return hs, (h_fin, st_fin)

@@ -87,8 +87,13 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.cell_scan import (_dummy_ids, _dummy_lens, _float0_like,
-                                     _is_fixed, _rh_mode, _unit_ids_table)
+from repro.kernels.backend import resolve_interpret, scan_compiler_params
+from repro.kernels.cell_scan import (_block_ds, _block_rows, _dot_nt,
+                                     _dot_tn, _dummy_ids, _dummy_lens,
+                                     _float0_like, _is_fixed, _pad_blocks,
+                                     _padded_block, _rh_mode, _stage_blocks,
+                                     _unit_ids_table, _unpad_blocks,
+                                     _unstage_blocks)
 from repro.kernels.lstm_scan import _pointwise_bwd, _pointwise_fwd
 
 F32 = jnp.float32
@@ -392,25 +397,39 @@ def _m3_inputs(mask, dtype, fixed, rev=None):
     return mask, spec
 
 
-def _pl_mm(x, w_ref, ids_ref, m_ref, t, d):
+def _pl_mm(x, w_ref, ids_ref, m_ref, t, d, padded, stage):
     """drop(x) @ w in f32 inside the kernel (compact when structured)."""
     if d.mode == "off":
         return jnp.dot(x, w_ref[...].astype(F32), preferred_element_type=F32)
     if d.mode == "structured":
         bs = d.block_size
+        _stage_blocks(x, stage, bs, padded)
         acc = jnp.zeros((x.shape[0], w_ref.shape[-1]), F32)
         for k in range(d.nk):                   # static unroll: exact-k masks
             bid = ids_ref[0 if d.fixed else t, k]
-            xb = jax.lax.dynamic_slice(x, (0, bid * bs), (x.shape[0], bs))
-            wb = w_ref[pl.ds(bid * bs, bs), :].astype(F32)
-            acc += jnp.dot(xb, wb, preferred_element_type=F32)
+            xb = _block_rows(stage, bid, bs, padded)              # (bs, B)
+            wb = _block_rows(w_ref, bid, bs, padded).astype(F32)  # (bs, G)
+            acc += _dot_tn(xb, wb)
         return acc * d.scale
     m = m_ref[0].astype(F32)
     return jnp.dot(x * m * d.scale, w_ref[...].astype(F32),
                    preferred_element_type=F32)
 
 
-def _pl_fwd_kernel(*args, nl, descs, n_steps, ragged):
+def _pl_scores(x, mem):
+    """(B, H) . (B, S, H) -> (B, S), as the batched matmul Mosaic lowers
+    (a rank-2 operand with no free axis does not)."""
+    return jnp.einsum("bqh,bsh->bqs", x[:, None], mem,
+                      preferred_element_type=F32)[:, 0]
+
+
+def _pl_context(a, mem):
+    """(B, S) . (B, S, H) -> (B, H), likewise."""
+    return jnp.einsum("bqs,bsh->bqh", a[:, None], mem,
+                      preferred_element_type=F32)[:, 0]
+
+
+def _pl_fwd_kernel(*args, nl, descs, paddeds, n_steps, ragged):
     ns = 2 * nl
     i = 0
     ids_refs = args[i:i + ns]; i += ns                              # noqa: E702
@@ -427,7 +446,8 @@ def _pl_fwd_kernel(*args, nl, descs, n_steps, ragged):
     h_rs = args[i:i + nl]; i += nl                                  # noqa: E702
     c_rs = args[i:i + nl]; i += nl                                  # noqa: E702
     hfin_r, cfin_r, ffin_r = args[i:i + 3]; i += 3                  # noqa: E702
-    h_s, c_s, feed_s = args[i:i + 3]
+    h_s, c_s, feed_s = args[i:i + 3]; i += 3                        # noqa: E702
+    stage = args[i] if i < len(args) else None
     site_w = [w_feed] + list(us) + list(ws)
 
     t = pl.program_id(0)
@@ -440,7 +460,7 @@ def _pl_fwd_kernel(*args, nl, descs, n_steps, ragged):
 
     def mm(x, i, extra_t):
         return _pl_mm(x, site_w[i], ids_refs[i], m_refs[i], extra_t,
-                      descs[i])
+                      descs[i], paddeds[i], stage)
 
     g = (gx0[0].astype(F32) + mm(feed_s[...], 0, t) + mm(h_s[0], 1, t))
     h, c = _pw_fwd(g, c_s[0])
@@ -455,11 +475,9 @@ def _pl_fwd_kernel(*args, nl, descs, n_steps, ragged):
         new_c.append(c)
         cur = h
     H = cur.shape[-1]
-    scores = jnp.einsum("bh,bsh->bs", cur, ep[...].astype(F32),
-                        preferred_element_type=F32) + sb[...].astype(F32)
+    scores = _pl_scores(cur, ep[...].astype(F32)) + sb[...].astype(F32)
     alpha = jax.nn.softmax(scores, axis=-1)
-    ctxv = jnp.einsum("bs,bsh->bh", alpha, eo[...].astype(F32),
-                      preferred_element_type=F32)
+    ctxv = _pl_context(alpha, eo[...].astype(F32))
     wc = w_comb[...].astype(F32)
     htil = jnp.tanh(jnp.dot(ctxv, wc[:H], preferred_element_type=F32)
                     + jnp.dot(cur, wc[H:], preferred_element_type=F32))
@@ -488,6 +506,26 @@ def _pl_fwd_kernel(*args, nl, descs, n_steps, ragged):
         ffin_r[...] = htil.astype(ffin_r.dtype)
 
 
+def _site_layout(nl, descs, ops, batch):
+    """Site weights as the kernels read them + their keep-block layout.
+
+    Structured sites get their weight's keep-blocks padded to the row tile
+    (cell_scan's "Keep-block row layout"); ``stage`` is the (rows, B) f32
+    scratch the sites stage their input in, one at a time, sized for the
+    widest (empty when no site is structured). Returns ``(w_feed, us, ws,
+    paddeds, stage)``.
+    """
+    ws = _site_weights(nl, ops)
+    paddeds = tuple(_padded_block(d.block_size, w.dtype)
+                    if d.mode == "structured" else d.block_size
+                    for d, w in zip(descs, ws))
+    ws = [_pad_blocks(w, d.block_size, p) if d.mode == "structured" else w
+          for d, w, p in zip(descs, ws, paddeds)]
+    rows = [w.shape[0] for d, w in zip(descs, ws) if d.mode == "structured"]
+    stage = [pltpu.VMEM((max(rows), batch), F32)] if rows else []
+    return ws[0], ws[1:1 + nl], ws[1 + nl:], paddeds, stage
+
+
 def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
     gx0 = ops["gx0"]
     T, B, G = gx0.shape
@@ -505,11 +543,12 @@ def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
         m_ins.append(m_in)
         m_specs.append(m_spec)
 
+    w_feed, us, ws, paddeds, stage = _site_layout(nl, descs, ops, B)
     seq = lambda shp: pl.BlockSpec((1, *shp), lambda t, *_: (t,) + (0,) * len(shp))
     const = lambda shp: pl.BlockSpec(shp, lambda t, *_: (0,) * len(shp))
 
-    kernel = functools.partial(_pl_fwd_kernel, nl=nl, descs=descs, n_steps=T,
-                               ragged=ragged)
+    kernel = functools.partial(_pl_fwd_kernel, nl=nl, descs=descs,
+                               paddeds=paddeds, n_steps=T, ragged=ragged)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -517,10 +556,10 @@ def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
             grid=(T,),
             in_specs=[
                 seq((B, G)),                                   # gx0
-                *([const((H, G))] * nl),                       # U_l
-                *([const((H, G))] * (nl - 1)),                 # W_l
+                *[const(u.shape) for u in us],                 # U_l
+                *[const(w.shape) for w in ws],                 # W_l
                 *([const((1, G))] * (nl - 1)),                 # b_l
-                const((H, G)), const((2 * H, H)),              # w_feed/w_comb
+                const(w_feed.shape), const((2 * H, H)),        # w_feed/w_comb
                 const((B, S, H)), const((B, S, H)),            # enc mem
                 const((B, S)),                                 # score_bias
                 const((nl, B, H)), const((nl, B, H)),          # h0/c0
@@ -536,7 +575,7 @@ def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
             ],
             scratch_shapes=[pltpu.VMEM((nl, B, H), F32),
                             pltpu.VMEM((nl, B, H), F32),
-                            pltpu.VMEM((B, H), F32)],
+                            pltpu.VMEM((B, H), F32)] + stage,
         ),
         out_shape=[jax.ShapeDtypeStruct((T, B, H), F32),
                    jax.ShapeDtypeStruct((T, B, S), F32),
@@ -545,10 +584,11 @@ def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
                    jax.ShapeDtypeStruct((nl, B, H), F32),
                    jax.ShapeDtypeStruct((nl, B, H), F32),
                    jax.ShapeDtypeStruct((B, H), F32)],
+        compiler_params=scan_compiler_params(),
         interpret=interpret,
-    )(*ids, lens, gx0, *ops["us"], *ops["ws"],
+    )(*ids, lens, gx0, *us, *ws,
       *[b.reshape(1, G) for b in ops["bs"]],
-      ops["w_feed"], ops["w_comb"], ops["enc_proj"], ops["enc_out"],
+      w_feed, ops["w_comb"], ops["enc_proj"], ops["enc_out"],
       ops["score_bias"], ops["h0"], ops["c0"], ops["feed0"], *m_ins)
     htil_seq, alpha_seq = outs[0], outs[1]
     gates_seqs = tuple(outs[2:2 + nl])
@@ -558,36 +598,34 @@ def _pallas_fwd(nl, descs, ops, masks, lengths, *, interpret):
     return htil_seq, gates_seqs, h_seqs, c_seqs, alpha_seq, finals
 
 
-def _pl_bp(dg, w_ref, ids_ref, m_ref, r, d, H):
+def _pl_bp(dg, w_ref, ids_ref, m_ref, r, d, H, padded, dstage):
     """Input grad through a site, inside the kernel (masked/compact)."""
     if d.mode == "off":
         return jnp.dot(dg, w_ref[...].astype(F32).T,
                        preferred_element_type=F32)
     if d.mode == "structured":
         bs = d.block_size
-        dx = jnp.zeros((dg.shape[0], H), F32)
+        dstage[...] = jnp.zeros(dstage.shape, F32)
         for k in range(d.nk):                   # static unroll
             bid = ids_ref[0 if d.fixed else r, k]
-            wb = w_ref[pl.ds(bid * bs, bs), :].astype(F32)
-            dxb = jnp.dot(dg, wb.T, preferred_element_type=F32) * d.scale
-            dx = jax.lax.dynamic_update_slice(dx, dxb, (0, bid * bs))
-        return dx
+            wb = _block_rows(w_ref, bid, bs, padded).astype(F32)  # (bs, G)
+            dstage[_block_ds(bid, bs, padded), :] = _dot_nt(wb, dg) * d.scale
+        return _unstage_blocks(dstage, H, bs, padded)
     m = m_ref[0].astype(F32)
     return (jnp.dot(dg, w_ref[...].astype(F32).T,
                     preferred_element_type=F32) * m * d.scale)
 
 
-def _pl_wg(x, dg, acc_ref, ids_ref, m_ref, r, d):
+def _pl_wg(x, dg, acc_ref, ids_ref, m_ref, r, d, padded, stage):
     """Accumulate the site's weight grad into its f32 scratch in place."""
     if d.mode == "structured":
         bs = d.block_size
-        B = x.shape[0]
+        _stage_blocks(x, stage, bs, padded)
         for k in range(d.nk):                   # static unroll
             bid = ids_ref[0 if d.fixed else r, k]
-            xb = jax.lax.dynamic_slice(x, (0, bid * bs), (B, bs))
-            cur = acc_ref[pl.ds(bid * bs, bs), :]
-            acc_ref[pl.ds(bid * bs, bs), :] = cur + jnp.dot(
-                xb.T, dg, preferred_element_type=F32) * d.scale
+            rows = _block_ds(bid, bs, padded)
+            acc_ref[rows, :] = acc_ref[rows, :] + jnp.dot(
+                stage[rows, :], dg, preferred_element_type=F32) * d.scale
         return
     if d.mode == "dense":
         x = x * m_ref[0].astype(F32) * d.scale
@@ -595,7 +633,7 @@ def _pl_wg(x, dg, acc_ref, ids_ref, m_ref, r, d):
                                           preferred_element_type=F32)
 
 
-def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
+def _pl_bwd_kernel(*args, nl, descs, paddeds, n_steps, ragged):
     ns = 2 * nl
     i = 0
     ids_refs = args[i:i + ns]; i += ns                              # noqa: E702
@@ -621,11 +659,21 @@ def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
     dh_s, dc_s, dfeed_s = args[i:i + 3]; i += 3                     # noqa: E702
     acc_s = args[i:i + ns]; i += ns                                 # noqa: E702
     db_s = args[i:i + nl - 1]; i += nl - 1                          # noqa: E702
-    dwc_s, dep_s, deo_s = args[i:i + 3]
+    dwc_s, dep_s, deo_s = args[i:i + 3]; i += 3                     # noqa: E702
+    stage, dstage = args[i:i + 2] if i < len(args) else (None, None)
     site_w = [w_feed] + list(us) + list(ws)
+    H = dy.shape[-1]
 
     t = pl.program_id(0)
     r = n_steps - 1 - t                      # the time step being processed
+
+    def bp(dg, s):
+        return _pl_bp(dg, site_w[s], ids_refs[s], m_refs[s], r, descs[s], H,
+                      paddeds[s], dstage)
+
+    def wg(x, dg, s):
+        _pl_wg(x, dg, acc_s[s], ids_refs[s], m_refs[s], r, descs[s],
+               paddeds[s], stage)
 
     @pl.when(t == 0)
     def _init():
@@ -640,7 +688,6 @@ def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
         dep_s[...] = jnp.zeros_like(dep_s)
         deo_s[...] = jnp.zeros_like(deo_s)
 
-    H = dy.shape[-1]
     htil_t = htil[0].astype(F32)
     alpha_t = alpha[0].astype(F32)
     eo32 = eo[...].astype(F32)
@@ -656,8 +703,7 @@ def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
     else:
         act, dhtil_c = None, dhtil
     dpre = dhtil_c * (1.0 - htil_t * htil_t)
-    ctxv = jnp.einsum("bs,bsh->bh", alpha_t, eo32,
-                      preferred_element_type=F32)
+    ctxv = _pl_context(alpha_t, eo32)
     wc = w_comb[...].astype(F32)
     dwc_s[:H] = dwc_s[:H] + jnp.dot(ctxv.T, dpre,
                                     preferred_element_type=F32)
@@ -665,16 +711,12 @@ def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
                                     preferred_element_type=F32)
     dctx = jnp.dot(dpre, wc[:H].T, preferred_element_type=F32)
     dcur = jnp.dot(dpre, wc[H:].T, preferred_element_type=F32)
-    dalpha = jnp.einsum("bh,bsh->bs", dctx, eo32,
-                        preferred_element_type=F32)
-    deo_s[...] = deo_s[...] + jnp.einsum("bs,bh->bsh", alpha_t, dctx,
-                                         preferred_element_type=F32)
+    dalpha = _pl_scores(dctx, eo32)
+    deo_s[...] = deo_s[...] + alpha_t[:, :, None] * dctx[:, None, :]
     dscores = alpha_t * (dalpha - jnp.sum(alpha_t * dalpha, -1,
                                           keepdims=True))
-    dcur = dcur + jnp.einsum("bs,bsh->bh", dscores, ep32,
-                             preferred_element_type=F32)
-    dep_s[...] = dep_s[...] + jnp.einsum("bs,bh->bsh", dscores, cur,
-                                         preferred_element_type=F32)
+    dcur = dcur + _pl_context(dscores, ep32)
+    dep_s[...] = dep_s[...] + dscores[:, :, None] * cur[:, None, :]
 
     dh_cur = [dh_s[l] for l in range(nl)]
     dh_cur[nl - 1] = dh_cur[nl - 1] + dcur
@@ -689,29 +731,22 @@ def _pl_bwd_kernel(*args, nl, descs, n_steps, ragged):
         dg, dc_prev = _pw_bwd(gates[l][0].astype(F32),
                               cp[l][0].astype(F32), cc[l][0].astype(F32),
                               dh_cell, dc_cell)
-        new_dh[l] = _pl_bp(dg, site_w[1 + l], ids_refs[1 + l],
-                           m_refs[1 + l], r, descs[1 + l], H)
-        _pl_wg(hp[l][0].astype(F32), dg, acc_s[1 + l], ids_refs[1 + l],
-               m_refs[1 + l], r, descs[1 + l])
+        new_dh[l] = bp(dg, 1 + l)
+        wg(hp[l][0].astype(F32), dg, 1 + l)
         new_dc[l] = dc_prev
         if ragged:
             new_dh[l] = new_dh[l] + jnp.where(act, 0.0, dh_cur[l])
             new_dc[l] = new_dc[l] + jnp.where(act, 0.0, dc_s[l])
         if l > 0:
-            dh_cur[l - 1] = dh_cur[l - 1] + _pl_bp(
-                dg, site_w[nl + l], ids_refs[nl + l], m_refs[nl + l], r,
-                descs[nl + l], H)
-            _pl_wg(hh[l - 1][0].astype(F32), dg, acc_s[nl + l],
-                   ids_refs[nl + l], m_refs[nl + l], r, descs[nl + l])
+            dh_cur[l - 1] = dh_cur[l - 1] + bp(dg, nl + l)
+            wg(hh[l - 1][0].astype(F32), dg, nl + l)
             db_s[l - 1][...] = db_s[l - 1][...] + dg.sum(axis=0)[None]
         else:
             dgx0_r[0] = dg.astype(dgx0_r.dtype)
-            dfeed_prev = _pl_bp(dg, site_w[0], ids_refs[0], m_refs[0], r,
-                                descs[0], H)
+            dfeed_prev = bp(dg, 0)
             if ragged:
                 dfeed_prev = dfeed_prev + jnp.where(act, 0.0, dhtil)
-            _pl_wg(fprev[0].astype(F32), dg, acc_s[0], ids_refs[0],
-                   m_refs[0], r, descs[0])
+            wg(fprev[0].astype(F32), dg, 0)
     for l in range(nl):
         dh_s[l] = new_dh[l]
         dc_s[l] = new_dc[l]
@@ -764,9 +799,11 @@ def _pallas_bwd(nl, descs, ops, masks, lengths, res, dout, *, interpret):
     rev = lambda shp: pl.BlockSpec((1, *shp),
                                    lambda t, *_: (T - 1 - t,) + (0,) * len(shp))
     const = lambda shp: pl.BlockSpec(shp, lambda t, *_: (0,) * len(shp))
+    w_feed, us, ws, paddeds, stage = _site_layout(nl, descs, ops, B)
+    w_shapes = [w.shape for w in (w_feed, *us, *ws)]    # canonical site order
 
-    kernel = functools.partial(_pl_bwd_kernel, nl=nl, descs=descs, n_steps=T,
-                               ragged=ragged)
+    kernel = functools.partial(_pl_bwd_kernel, nl=nl, descs=descs,
+                               paddeds=paddeds, n_steps=T, ragged=ragged)
     outs = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -777,9 +814,9 @@ def _pallas_bwd(nl, descs, ops, masks, lengths, res, dout, *, interpret):
                 *([rev((B, G))] * nl),                         # gates_l
                 *([rev((B, H))] * (4 * nl)),                   # h/h_prev/c/c_prev
                 rev((B, H)), rev((B, H)), rev((B, S)),         # htil/fprev/alpha
-                *([const((H, G))] * nl),                       # U_l
-                *([const((H, G))] * (nl - 1)),                 # W_l
-                const((H, G)), const((2 * H, H)),              # w_feed/w_comb
+                *[const(u.shape) for u in us],                 # U_l
+                *[const(w.shape) for w in ws],                 # W_l
+                const(w_feed.shape), const((2 * H, H)),        # w_feed/w_comb
                 const((B, S, H)), const((B, S, H)),            # enc mem
                 const((nl, B, H)), const((nl, B, H)),          # dhT/dcT
                 const((B, H)),                                 # dfT
@@ -787,10 +824,9 @@ def _pallas_bwd(nl, descs, ops, masks, lengths, res, dout, *, interpret):
             ],
             out_specs=[
                 rev((B, G)),                                   # dgx0
-                *([const((H, G))] * nl),                       # dU_l
-                *([const((H, G))] * (nl - 1)),                 # dW_l
+                *[const(shp) for shp in w_shapes[1:]],         # dU_l/dW_l
                 *([const((1, G))] * (nl - 1)),                 # db_l
-                const((H, G)), const((2 * H, H)),              # dWf/dWcomb
+                const(w_shapes[0]), const((2 * H, H)),         # dWf/dWcomb
                 const((B, S, H)), const((B, S, H)),            # dEp/dEo
                 const((nl, B, H)), const((nl, B, H)),          # dh0/dc0
                 const((B, H)),                                 # dfeed0
@@ -798,33 +834,36 @@ def _pallas_bwd(nl, descs, ops, masks, lengths, res, dout, *, interpret):
             scratch_shapes=[pltpu.VMEM((nl, B, H), F32),
                             pltpu.VMEM((nl, B, H), F32),
                             pltpu.VMEM((B, H), F32)]
-            + [pltpu.VMEM((H, G), F32)] * ns
+            + [pltpu.VMEM(shp, F32) for shp in w_shapes]
             + [pltpu.VMEM((1, G), F32)] * (nl - 1)
             + [pltpu.VMEM((2 * H, H), F32),
-               pltpu.VMEM((B, S, H), F32), pltpu.VMEM((B, S, H), F32)],
+               pltpu.VMEM((B, S, H), F32), pltpu.VMEM((B, S, H), F32)]
+            + stage * 2,
         ),
         out_shape=[jax.ShapeDtypeStruct((T, B, G), F32),
-                   *[jax.ShapeDtypeStruct((H, G), F32)] * (2 * nl - 1),
+                   *[jax.ShapeDtypeStruct(shp, F32) for shp in w_shapes[1:]],
                    *[jax.ShapeDtypeStruct((1, G), F32)] * (nl - 1),
-                   jax.ShapeDtypeStruct((H, G), F32),
+                   jax.ShapeDtypeStruct(w_shapes[0], F32),
                    jax.ShapeDtypeStruct((2 * H, H), F32),
                    jax.ShapeDtypeStruct((B, S, H), F32),
                    jax.ShapeDtypeStruct((B, S, H), F32),
                    jax.ShapeDtypeStruct((nl, B, H), F32),
                    jax.ShapeDtypeStruct((nl, B, H), F32),
                    jax.ShapeDtypeStruct((B, H), F32)],
+        compiler_params=scan_compiler_params(),
         interpret=interpret,
     )(*ids, lens, d_htil, *gates_seqs, *h_seqs, *h_prev_seqs, *c_seqs,
-      *c_prev_seqs, htil_seq, feed_prev_seq, alpha_seq, *ops["us"],
-      *ops["ws"], ops["w_feed"], ops["w_comb"], ops["enc_proj"],
-      ops["enc_out"], d_hfin, d_cfin, d_ffin, *m_ins)
+      *c_prev_seqs, htil_seq, feed_prev_seq, alpha_seq, *us, *ws, w_feed,
+      ops["w_comb"], ops["enc_proj"], ops["enc_out"], d_hfin, d_cfin, d_ffin,
+      *m_ins)
     i = 0
     dgx = outs[i]; i += 1                                           # noqa: E702
     dus = list(outs[i:i + nl]); i += nl                             # noqa: E702
     dws = list(outs[i:i + nl - 1]); i += nl - 1                     # noqa: E702
     dbs = [b[0] for b in outs[i:i + nl - 1]]; i += nl - 1           # noqa: E702
     dwf, dwcomb, dep, deo, dh0, dc0, dfeed0 = outs[i:i + 7]
-    accs = [dwf] + dus + dws
+    accs = [_unpad_blocks(a, d.block_size, p) if d.mode == "structured"
+            else a for a, d, p in zip([dwf] + dus + dws, descs, paddeds)]
     return (dgx, accs, dbs, dwcomb, dep, deo, dh0, dc0, dfeed0)
 
 
@@ -933,12 +972,10 @@ def decoder_scan(gx0: jax.Array, us: Tuple[jax.Array, ...],
     pairs = [_mk_site(*s) for s in sites]
     descs = tuple(p[0] for p in pairs)
     site_masks = tuple(p[1] for p in pairs)
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     ops = dict(gx0=gx0, us=tuple(us), ws=tuple(ws), bs=tuple(bs),
                w_feed=w_feed, w_comb=w_comb, enc_proj=enc_proj,
                enc_out=enc_out, score_bias=score_bias, h0=h0, c0=c0,
                feed0=feed0)
     htil, h_fin, c_fin, feed_fin = _decoder_scan_jit(
-        descs, impl, bool(interpret), ops, site_masks, lengths)
+        descs, impl, resolve_interpret(interpret), ops, site_masks, lengths)
     return htil, (h_fin, c_fin, feed_fin)

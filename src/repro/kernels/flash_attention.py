@@ -25,6 +25,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 NEG_INF = -1e30
 
 
@@ -275,8 +277,7 @@ def flash_attention(q, k, v, causal=True, window=None, bq=512, bk=512,
 
 
 def _resolve(q, bq, bk, Sq, Sk, interpret):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     bq = min(bq, Sq)
     while Sq % bq:
         bq -= 1

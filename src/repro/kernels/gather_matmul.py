@@ -23,8 +23,9 @@ copies ever land in HBM.
 
 Tiling: grid = (M/bm, OUT/b_out, CONTRACT/b_k), k innermost; fp32 VMEM
 accumulator, write-out on the last k step. The dropout ``block_size`` doubles
-as the gathered dimension's tile, so production masks use 128/256 (MXU lane
-aligned); ``interpret=True`` validates any size on CPU.
+as the gathered dimension's tile, so on TPU it must be a multiple of 128 (a
+lane-dimension tile); any other size raises ``ValueError`` at trace time
+rather than failing inside Mosaic. Interpret mode takes any size.
 """
 from __future__ import annotations
 
@@ -35,6 +36,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.backend import resolve_interpret
 
 
 def _mm_kernel(ids_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int, transpose_b: bool):
@@ -51,6 +54,25 @@ def _mm_kernel(ids_ref, a_ref, b_ref, o_ref, acc_ref, *, n_k: int, transpose_b: 
     @pl.when(pl.program_id(2) == n_k - 1)
     def _flush():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+LANES = 128   # lane width of a TPU vreg: the minor dimension of a tile
+
+
+def _check_tile(block_size, gathered, interpret):
+    """Raise at trace time when ``block_size`` cannot tile the kernel on TPU.
+
+    The block size is the tile of the gathered dimension, and in every
+    variant some operand holds that tile on its lanes, which Mosaic takes
+    only in multiples of 128 (or whole). Interpret mode takes any size.
+    """
+    if interpret or block_size % LANES == 0 or block_size == gathered:
+        return
+    raise ValueError(
+        f"block_size={block_size} cannot tile gather_matmul on TPU: the "
+        f"kernel tiles the gathered dimension ({gathered}) by the block "
+        f"size, which must be a multiple of the {LANES}-lane tile there; "
+        f"use such a block size or impl='xla'")
 
 
 def _pad_to(x, axis, mult):
@@ -76,8 +98,9 @@ def gather_matmul(a: jax.Array, b: jax.Array, keep_blocks: jax.Array, *,
                   bk: Optional[int] = None,
                   interpret: Optional[bool] = None) -> jax.Array:
     """See module docstring. a: (M, Ka), b: (K, N), keep_blocks: (nk,) int32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    _check_tile(block_size, b.shape[1 if gather == "b_cols" else 0],
+                interpret)
     nk = keep_blocks.shape[0]
     bs = block_size
     M = a.shape[0]
@@ -194,8 +217,8 @@ def gather_matmul_stepped(a: jax.Array, b: jax.Array, keep_blocks: jax.Array,
       not transpose_b (FP): a (T, M, nk*bs | K) -> y (T, M, N) = a_c @ b[kept_t]
       transpose_b     (BP): a (T, M, N)         -> y (T, M, nk*bs) = a @ b[kept_t].T
     """
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
+    _check_tile(block_size, b.shape[0], interpret)
     T, nk = keep_blocks.shape
     bs = block_size
     assert a.shape[0] == T, (a.shape, T)

@@ -27,6 +27,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _kernel(blk_e_ref, x_ref, w_ref, o_ref, acc_ref, *, nk: int):
     @pl.when(pl.program_id(2) == 0)
@@ -49,8 +51,7 @@ def grouped_matmul(x: jax.Array, w: jax.Array, blk_expert: jax.Array, *,
     """x: (T, D) expert-sorted rows (T % bm == 0, blocks expert-pure);
     w: (E, D, F); blk_expert: (T//bm,) int32 expert id per row block.
     -> y: (T, F)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     T, D = x.shape
     E, _, F = w.shape
     assert T % bm == 0, (T, bm)

@@ -19,6 +19,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import resolve_interpret
+
 
 def _kernel(i_ref, f_ref, g_ref, o_ref, c_ref, h_out, c_out, *, forget_bias):
     i = i_ref[...].astype(jnp.float32)
@@ -39,8 +41,7 @@ def lstm_pointwise(gates: jax.Array, c_prev: jax.Array, *,
                    bh: Optional[int] = None,
                    interpret: Optional[bool] = None):
     """gates: (B, 4H), c_prev: (B, H) -> (h', c') each (B, H)."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+    interpret = resolve_interpret(interpret)
     B, H4 = gates.shape
     H = H4 // 4
     assert c_prev.shape == (B, H)

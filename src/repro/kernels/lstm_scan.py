@@ -33,15 +33,15 @@ materializes a dense (H, 4H) zeros+scatter every step, FIXED schedules
 hoist the U gather out of the scan entirely and keep dU compact until one
 final scatter, and the gate bias rides in gx (masked-dense was tried first
 and measured ~0.7x of scheduled at Zaremba-large geometry on CPU — the
-1/(1-p) extra FLOPs beat the saved gathers). The pallas path auto-falls
-back to interpret mode off TPU — correct but not fast.
+1/(1-p) extra FLOPs beat the saved gathers). The pallas path compiles
+for the TPU and runs in interpret mode elsewhere (correct, not fast).
 
-VMEM budget: U (H, 4H) must fit on-core alongside the (B, ·) working set —
-~f32 H<=700 / bf16 H<=1000 on a 16 MB core. Beyond that the natural
-extension is sharding H across cores (persistent-RNN style); not done
-here. Tile alignment: on real TPU the dynamic slices want ``block_size`` a
-multiple of the lane width (128) and B a multiple of 8; interpret mode
-(CPU) validates any size.
+VMEM budget: the backward keeps U, dU and an f32 dU accumulator resident,
+each (H, 4H). Zaremba-medium (H=650, f32) compiles for v5e; Zaremba-large
+(H=1500, f32) exceeds a v5e core's VMEM in the backward. Beyond that the
+natural extension is sharding H across cores (persistent-RNN style); not
+done here. Any ``block_size`` compiles: each keep-block is padded to the
+8-row tile (cell_scan.py, "Keep-block row layout"), e.g. 65 -> 72 rows.
 """
 from __future__ import annotations
 

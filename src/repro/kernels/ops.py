@@ -1,7 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels (dispatch layer).
 
-On TPU the kernels run compiled; elsewhere they run in interpret mode
-(auto-detected), which executes the kernel body on CPU for correctness.
+On a TPU backend the kernels run compiled; elsewhere they run in
+interpret mode, which executes the kernel body on CPU for correctness
+(``kernels/backend.py``; an ahead-of-time compile for a TPU passes
+``interpret=False``).
 ``ref.py`` holds the independent pure-jnp oracles used by the tests.
 """
 from repro.kernels.cell_scan import cell_scan

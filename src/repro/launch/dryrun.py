@@ -27,6 +27,8 @@ import json
 import time
 import traceback
 
+import jax
+
 from repro import configs
 from repro.configs.shapes import SHAPES
 from repro.distributed import sharding as shd
@@ -47,7 +49,7 @@ def run_cell(spec, shape, mesh, rules, *, use_dropout, dropout="",
                             use_dropout=use_dropout, dropout=dropout,
                             engine=engine)
     t0 = time.time()
-    with mesh:
+    with jax.set_mesh(mesh):
         lowered = cell.jitted.lower(*cell.example_args)
         t_lower = time.time() - t0
         compiled = lowered.compile()

@@ -12,18 +12,27 @@ the real device count).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are all Auto: sharding is propagated by the
+    compiler from the arrays' NamedShardings and the ``shard_act``
+    constraints, as the model code assumes (``jax.make_mesh`` defaults to
+    Explicit axes, under which every op must type its own sharding)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever this host actually has (CPU tests: 1 device)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def make_data_mesh(n: int):
@@ -39,4 +48,5 @@ def make_data_mesh(n: int):
                          f"{len(devs)} host devices")
     import numpy as np
     from jax.sharding import Mesh
-    return Mesh(np.asarray(devs[:n]).reshape(n, 1), ("data", "model"))
+    return Mesh(np.asarray(devs[:n]).reshape(n, 1), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)

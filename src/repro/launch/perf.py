@@ -14,6 +14,8 @@ import dataclasses
 import json
 import time
 
+import jax
+
 from repro import configs
 from repro.configs.shapes import SHAPES
 from repro.distributed import sharding as shd
@@ -141,7 +143,7 @@ def main():
         cell = steps.build_cell(spec, cfg, shape, mesh, rules,
                                 use_dropout=use_dropout)
         t0 = time.time()
-        with mesh:
+        with jax.set_mesh(mesh):
             compiled = cell.jitted.lower(*cell.example_args).compile()
         from repro.launch import hlo_cost, roofline as rf
         la = hlo_cost.analyze_hlo(compiled.as_text())

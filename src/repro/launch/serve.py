@@ -19,6 +19,7 @@ import numpy as np
 
 from repro import configs
 from repro.distributed import sharding as shd
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
 from repro.serving import DecodeEngine, Request, prompt_prefill, serve
@@ -36,6 +37,7 @@ def _ragged_trace(n: int, vocab: int, prompt_max: int, gen_max: int,
 
 
 def main(argv=None):
+    compile_cache.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

@@ -27,6 +27,7 @@ from repro.configs import adapters
 from repro.core.dropout_plan import DropoutPlan
 from repro.data import synthetic
 from repro.distributed import sharding as shd
+from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
 
@@ -69,6 +70,7 @@ def make_batch_fn(spec, cfg, batch: int, seq: int, seed: int):
 
 
 def main(argv=None):
+    compile_cache.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -319,6 +319,30 @@ class TestGuards:
         flat = spec0 if isinstance(spec0, tuple) else (spec0,)
         assert "data" in flat, out2["w"].sharding.spec
 
+    def test_meshes_are_auto_typed(self):
+        """The model code leaves sharding to propagation, which Explicit
+        axes (``jax.make_mesh``'s default) refuse."""
+        auto = (jax.sharding.AxisType.Auto,) * 2
+        assert mesh_mod.make_host_mesh().axis_types == auto
+        assert mesh_mod.make_data_mesh(1).axis_types == auto
+
+    def test_shard_act_follows_the_set_mesh(self):
+        """shard_act constrains under ``jax.set_mesh`` and is the identity
+        without a mesh."""
+        mesh = mesh_mod.make_host_mesh()
+        rules = shd.rules_for_mesh(mesh)
+        x = jnp.ones((4, 8), jnp.float32)
+
+        def f(x):
+            return shd.shard_act(x, ("batch", None), rules) * 2
+
+        assert "sharding_constraint" not in jax.jit(f).lower(x).as_text()
+        with jax.set_mesh(mesh):
+            text = jax.jit(f).lower(x).as_text()
+            y = jax.jit(f)(x)
+        assert "sharding_constraint" in text, text
+        np.testing.assert_array_equal(np.asarray(y), 2 * np.ones((4, 8)))
+
     def test_weight_matches_unsharded_denominator(self):
         """loss_weight(kind) returns exactly the weight the unsharded loss
         divides by: loss * weight is additive across row blocks."""

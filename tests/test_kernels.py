@@ -137,6 +137,19 @@ class TestGatherMatmulStepped:
                                               transpose_b=True)
         np.testing.assert_allclose(y, y_ref, rtol=1e-4, atol=1e-4)
 
+    @pytest.mark.parametrize("transpose_b", [False, True])
+    def test_untileable_block_raises(self, transpose_b):
+        """Compiled for TPU, a block size that is no lane multiple is
+        refused at trace time, naming the size and the tile."""
+        T, M, H, N, bs = 3, 4, 130, 16, 65
+        kb = jnp.zeros((T, 1), jnp.int32)
+        a = mk((T, M, N) if transpose_b else (T, M, bs), jnp.float32, 17)
+        with pytest.raises(ValueError, match="block_size=65.*128"):
+            ops.gather_matmul_stepped(a, mk((H, N), jnp.float32, 18), kb,
+                                      block_size=bs, a_is_compact=True,
+                                      transpose_b=transpose_b,
+                                      interpret=False)
+
     def test_per_step_masks_differ(self):
         """Each step really contracts its own kept blocks (not step 0's)."""
         T, M, H, N, bs = 3, 4, 32, 16, 8
