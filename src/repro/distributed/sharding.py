@@ -187,11 +187,18 @@ def shard_put(tree, axes_tree, rules: ShardingRules, mesh: Mesh):
 def shard_act(x: jax.Array, axes: Sequence[Optional[str]],
               rules: Optional[ShardingRules]) -> jax.Array:
     """with_sharding_constraint by logical names; no-op when rules is None
-    (CPU tests) or when no mesh is set (``jax.set_mesh``)."""
+    (CPU tests) or when no mesh is set (``jax.set_mesh``).
+
+    Inside ``shard_map`` the mapped axes are Manual: ``x`` is already this
+    shard's slice along them, so only the mesh's Auto axes are constrained
+    (none at all when every axis is mapped)."""
     if rules is None:
         return x
     mesh = jax.sharding.get_abstract_mesh()
-    if mesh.empty:
+    if mesh.empty or mesh.are_all_axes_manual:
         return x
+    if mesh.manual_axes:
+        rules = dataclasses.replace(rules, mesh_axes=tuple(
+            a for a in rules.mesh_axes if a not in mesh.manual_axes))
     spec = logical_to_pspec(axes, rules, x.shape, mesh)
     return jax.lax.with_sharding_constraint(x, spec)

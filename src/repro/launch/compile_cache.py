@@ -1,9 +1,10 @@
 """JAX's persistent compilation cache for the entry points.
 
 Compiling the training step for a TPU takes tens of seconds; the cache
-lets the next process that builds the same program load it instead. The
-cache key includes its directory, so the default directory is a fixed
-path inside the checkout (``.jax_cache``, listed in ``.gitignore``).
+lets the next process that builds the same program load it instead. That
+process looks in the same directory only if its name does not change, so
+the default is a fixed path inside the checkout (``.jax_cache``, listed
+in ``.gitignore``).
 """
 from __future__ import annotations
 

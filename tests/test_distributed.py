@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import PartitionSpec as P
 
 try:
     import hypothesis
@@ -114,12 +115,15 @@ _CELLS = {"lstm_lm": _lm_cell, "nmt": _nmt_cell, "tagger": _tagger_cell,
 
 
 def _check_sharded(kind, cfg, lfn, params, batch, d, *, step=1,
-                   rtol=5e-4, atol=1e-5):
-    """Sharded (d devices) loss/grads == single-device loss/grads."""
+                   rtol=5e-4, atol=1e-5, with_rules=False):
+    """Sharded (d devices) loss/grads == single-device loss/grads.
+    ``with_rules`` passes the mesh's sharding rules into the shard_map body,
+    as ``launch/train.py --mesh`` does."""
     ref_loss, ref_grads = jax.value_and_grad(
         lambda p: lfn(p, batch, cfg, drop_key=DROP_KEY, step=step))(params)
     mesh = mesh_mod.make_data_mesh(d)
-    vag = steps_mod.make_sharded_loss_and_grad(kind, cfg, mesh)
+    rules = shd.rules_for_mesh(mesh) if with_rules else None
+    vag = steps_mod.make_sharded_loss_and_grad(kind, cfg, mesh, rules=rules)
     loss, grads = jax.jit(vag)(params, batch, step, DROP_KEY)
     np.testing.assert_allclose(float(loss), float(ref_loss), rtol=1e-5,
                                err_msg=f"{kind} d={d} loss")
@@ -162,6 +166,18 @@ class TestShardedEquivalence:
     @pytest.mark.parametrize("engine", ENGINES)
     def test_xlstm(self, host_devices, case, engine):
         _check_sharded(*_xlstm_cell(case, engine), _cap(host_devices))
+
+    @pytest.mark.parametrize("d", (1, 2, 4))
+    def test_xlstm_with_rules(self, d):
+        """xlstm's shard_act runs inside the shard_map body, where the data
+        axis is Manual; a per-shard batch that divides by the axis size
+        must train, not trip with_sharding_constraint."""
+        if d > len(jax.devices()):
+            pytest.skip(f"needs {d} host devices")
+        kind, cfg, lfn, params, _ = _xlstm_cell("case3", "fused")
+        tok = jax.random.randint(KEY, (d * d * 2, 8), 0, 40)
+        _check_sharded(kind, cfg, lfn, params,
+                       {"tokens": tok, "labels": tok}, d, with_rules=True)
 
     def test_fixed_time_pattern_per_family(self, host_devices):
         """case2 (RANDOM x FIXED) on the remaining families: one dense
@@ -342,6 +358,26 @@ class TestGuards:
             y = jax.jit(f)(x)
         assert "sharding_constraint" in text, text
         np.testing.assert_array_equal(np.asarray(y), 2 * np.ones((4, 8)))
+
+    def test_shard_act_skips_manual_axes(self):
+        """Inside shard_map over "data" only the Auto "model" axis is
+        constrained; over every axis shard_act is the identity."""
+        mesh = mesh_mod.make_host_mesh()
+        rules = shd.rules_for_mesh(mesh)
+        x = jnp.ones((4, 8), jnp.float32)
+
+        def body(x):
+            return shd.shard_act(x, ("batch", "mlp"), rules) * 2
+
+        def lowered(axis_names):
+            f = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                              out_specs=P("data"), axis_names=axis_names)
+            return jax.jit(f).lower(x).as_text()
+
+        partial = [l for l in lowered({"data"}).splitlines()
+                   if "sharding_constraint" in l and "model" in l]
+        assert len(partial) == 1 and '"data"' not in partial[0], partial
+        assert "sharding_constraint %arg" not in lowered({"data", "model"})
 
     def test_weight_matches_unsharded_denominator(self):
         """loss_weight(kind) returns exactly the weight the unsharded loss
