@@ -54,8 +54,11 @@ LR = 1e-3                        # launch/train.py's default
 # The gradient check is per leaf: the worst element error relative to that
 # leaf's max magnitude, the recurrent U leaves included, so a dU row scattered
 # into the wrong keep-block shows even where the global norm would not.
-LOSS_RTOL = 5e-3
-GRAD_RTOL = 5e-2
+# On a TPU v5 lite the loss differed by 1.0e-7 and the worst leaf by 1.1e-3
+# (U: 1.1e-3); a dU scatter shifted by one keep-block gives about 1.4
+# (CPU, interpret mode, smoke width).
+LOSS_RTOL = 1e-5
+GRAD_RTOL = 1e-2
 # data-parallel vs one chip: the same math on 5-row shards, psum'd; the same
 # per-leaf measure.
 DP_RTOL = 1e-3
