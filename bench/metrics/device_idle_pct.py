@@ -1,0 +1,6 @@
+"""Share of the traced window in which no operation ran on the device."""
+
+
+def read(r):
+    w = r.trace["window_s"]
+    return 100.0 * (1.0 - r.trace["busy_s"] / w) if w > 0 else None
