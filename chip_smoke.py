@@ -8,8 +8,10 @@ published width (vocab 10000, embed and hidden 650, 2 layers, batch 20,
 unroll 35) from random weights made from seed 0, through the functions
 ``repro.launch.train`` uses:
 
-  published      ``repro.launch.train.main`` on the config as published
-                 (scheduled engine, xla impl); 5 steps, every loss finite.
+  published      ``repro.launch.train.main`` on the arch's ``spec.full()``
+                 config (case III rate 0.5, block 1, at embed/nr/rh/out;
+                 scheduled engine, xla impl), which is not Zaremba's own
+                 case I recipe; 5 steps, every loss finite.
   paper-path     the fused engine with case III dropout at rate 0.5 and
                  block size 65 on all four sites; the recurrent ("rh")
                  site runs the Pallas scan kernel, the others xla. The
@@ -82,7 +84,7 @@ def _check_finite(name, losses):
 
 
 def published():
-    """launch/train.py's own entry point on the published config."""
+    """launch/train.py's own entry point on the arch's full config."""
     from repro.launch import train
     log = io.StringIO()
     with contextlib.redirect_stdout(log):

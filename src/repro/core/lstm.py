@@ -36,6 +36,10 @@ Three execution engines share the same numerics (tests assert equivalence):
 
 Time iteration is ``jax.lax.scan`` (compact HLO, O(1) program size in T);
 the fused engine replaces the Phase-B scan with the persistent kernel.
+
+Phase A runs under the ``phase_a`` profiler scope unless its NR site runs
+the Pallas gather matmul; no scope encloses Phase B, which the fused
+engine's nested jit names (``launch/trace_names.py``).
 """
 from __future__ import annotations
 
@@ -46,6 +50,7 @@ import jax.numpy as jnp
 
 from repro.core import layers as L
 from repro.core.dropout_plan import NULL_CTX, DropoutCtx
+from repro.launch import trace_names as tn
 
 ENGINES = ("scheduled", "stepwise", "fused")
 
@@ -146,11 +151,14 @@ def _lstm_stack_scheduled(params, x_seq, state, *, ctx, site, forget_bias,
     inp = x_seq
     h_fin, c_fin = [], []
     for l in range(num_layers):
-        nr_sched = ctx.schedule(f"{site}/layer{l}/nr", T, batch,
-                                inp.shape[-1])
-        rh_sched = ctx.schedule(f"{site}/layer{l}/rh", T, batch, hidden)
-        # Phase A: time-batched NR gate matmul (no sequential dependence).
-        gx = L.dense_sdrop_scheduled({"w": params[l]["W"]}, inp, nr_sched)
+        nr_site = f"{site}/layer{l}/nr"
+        with tn.scope(tn.PHASE_A, ctx.spec(nr_site).impl):
+            nr_sched = ctx.schedule(nr_site, T, batch, inp.shape[-1])
+            rh_sched = ctx.schedule(f"{site}/layer{l}/rh", T, batch, hidden)
+            # Phase A: time-batched NR gate matmul (no sequential
+            # dependence).
+            gx = L.dense_sdrop_scheduled({"w": params[l]["W"]}, inp,
+                                         nr_sched)
         U, b = params[l]["U"], params[l]["b"]
         # PER_STEP masks ride through the scan as xs; FIXED/inactive ones
         # are a single state closed over as a scan constant.
@@ -203,12 +211,13 @@ def _lstm_stack_fused(params, x_seq, state, *, ctx, site, forget_bias,
     inp = x_seq
     h_fin, c_fin = [], []
     for l in range(num_layers):
-        nr_sched = ctx.schedule(f"{site}/layer{l}/nr", T, batch,
-                                inp.shape[-1])
-        rh_sched = ctx.schedule(f"{site}/layer{l}/rh", T, batch, hidden)
-        # Phase A: time-batched NR gate matmul, bias folded in.
-        gx = L.dense_sdrop_scheduled(
-            {"w": params[l]["W"], "b": params[l]["b"]}, inp, nr_sched)
+        nr_site = f"{site}/layer{l}/nr"
+        with tn.scope(tn.PHASE_A, ctx.spec(nr_site).impl):
+            nr_sched = ctx.schedule(nr_site, T, batch, inp.shape[-1])
+            rh_sched = ctx.schedule(f"{site}/layer{l}/rh", T, batch, hidden)
+            # Phase A: time-batched NR gate matmul, bias folded in.
+            gx = L.dense_sdrop_scheduled(
+                {"w": params[l]["W"], "b": params[l]["b"]}, inp, nr_sched)
         kw, impl = {}, pointwise_impl
         if not rh_sched.inactive:
             impl = rh_sched.spec.impl

@@ -40,6 +40,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.dropout_plan import BatchShard
+from repro.launch import trace_names as tn
 
 # Mesh axes a batch dim shards over, in linearization order (the same
 # physical mapping distributed/sharding.py DEFAULT_RULES gives "batch").
@@ -112,13 +113,15 @@ def sharded_value_and_grad(loss_fn: Callable, weight_fn: Callable,
         lsum, grads = jax.value_and_grad(
             lambda p: loss_fn(p, batch, step, key, shard)
             * w.astype(jnp.float32))(params)
-        wsum = jax.lax.psum(w, axes) if axes else w
-        denom = jnp.maximum(wsum, 1.0)
-        if axes:
-            lsum = jax.lax.psum(lsum, axes)
-            grads = jax.tree.map(lambda g: jax.lax.psum(g, axes), grads)
-        loss = lsum / denom
-        grads = jax.tree.map(lambda g: (g / denom).astype(g.dtype), grads)
+        with jax.named_scope(tn.GRAD_PSUM):
+            wsum = jax.lax.psum(w, axes) if axes else w
+            denom = jnp.maximum(wsum, 1.0)
+            if axes:
+                lsum = jax.lax.psum(lsum, axes)
+                grads = jax.tree.map(lambda g: jax.lax.psum(g, axes), grads)
+            loss = lsum / denom
+            grads = jax.tree.map(lambda g: (g / denom).astype(g.dtype),
+                                 grads)
         return loss, grads
 
     def vag(params, batch, step, key):

@@ -10,12 +10,18 @@ Fault tolerance in the loop:
     is a pure function of (seed, step) so no data state is needed;
   * a step-time watchdog logs stragglers (steps slower than
     --straggler-factor x the running median are flagged; on a real fleet
-    this feeds the controller that evicts/replaces the slow host).
+    this feeds the controller that evicts/replaces the slow host), with
+    the compiles that fell inside the step (``CompileCounter``).
+
+Each step runs under the profiler's step annotation with one span per
+host phase (``launch/trace_names.py``); ``--profile-dir DIR
+--profile-steps A:B`` traces steps A to B into DIR (docs/tracing.md).
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -30,6 +36,7 @@ from repro.distributed import sharding as shd
 from repro.launch import compile_cache
 from repro.launch import mesh as mesh_mod
 from repro.launch import steps as steps_mod
+from repro.launch import trace_names as tn
 
 
 def make_batch_fn(spec, cfg, batch: int, seq: int, seed: int):
@@ -69,6 +76,97 @@ def make_batch_fn(spec, cfg, batch: int, seq: int, seed: int):
     raise ValueError(spec.kind)
 
 
+class CompileCounter:
+    """Counts XLA compiles, and their seconds, while it is entered: a
+    ``jax.monitoring`` listener on the event JAX times around each
+    backend compile (a load from the persistent compilation cache
+    included)."""
+
+    EVENT = "/jax/core/compile/backend_compile_duration"
+
+    def __init__(self):
+        self.n, self.seconds = 0, 0.0
+
+    def _listen(self, event: str, duration: float, **_) -> None:
+        if event == self.EVENT:
+            self.n += 1
+            self.seconds += duration
+
+    def __enter__(self):
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
+        return self
+
+    def __exit__(self, *exc):
+        jax.monitoring.unregister_event_duration_listener(self._listen)
+
+
+class TrainLoop:
+    """The body of ``main``'s loop: one call of ``step`` is one training
+    step. The batch goes to the device leaf by leaf, the jitted step is
+    called with ``jnp.int32(step)`` and ``fold_in(key, step)``, the loss
+    is synced with ``float``, the step's time goes to the straggler
+    watchdog and the log, and ``save(step, params, opt_state)`` (the
+    checkpoint; true to stop) closes it. The step runs under the
+    profiler's ``StepTraceAnnotation`` with a span per phase
+    (``trace_names.SPANS``); outside a trace they cost next to nothing.
+    Compiles are counted from the end of the first step."""
+
+    def __init__(self, jitted: Callable, batch_fn: Callable, key, *,
+                 compiles: CompileCounter, straggler_factor: float = 3.0,
+                 log_every: int = 10, save: Optional[Callable] = None):
+        self.jitted, self.batch_fn, self.key = jitted, batch_fn, key
+        self.straggler_factor, self.log_every = straggler_factor, log_every
+        self.save, self.compiles = save, compiles
+        self.times = []
+        self.base = None        # (compiles, seconds) after the first step
+
+    def compiles_after_first(self):
+        """(compiles, seconds) since the end of the first step."""
+        if self.base is None:
+            return 0, 0.0
+        return (self.compiles.n - self.base[0],
+                self.compiles.seconds - self.base[1])
+
+    def step(self, step: int, params, opt_state):
+        """-> (params, opt_state, loss, stop)."""
+        span = jax.profiler.TraceAnnotation
+        n0, s0 = self.compiles.n, self.compiles.seconds
+        with jax.profiler.StepTraceAnnotation(tn.STEP, step_num=step):
+            t0 = time.time()
+            with span(tn.BATCH):
+                batch = jax.tree.map(jnp.asarray, self.batch_fn(step))
+            with span(tn.ARGS):
+                args = (jnp.int32(step), jax.random.fold_in(self.key, step))
+            with span(tn.DISPATCH):
+                params, opt_state, loss = self.jitted(params, opt_state,
+                                                      batch, *args)
+            with span(tn.SYNC):
+                loss = float(loss)
+            dt = time.time() - t0
+            self.times.append(dt)
+            med = float(np.median(self.times[-50:]))
+            if dt > self.straggler_factor * med and len(self.times) > 10:
+                print(f"[straggler] step {step} took {dt:.2f}s "
+                      f"(median {med:.2f}s; {self.compiles.n - n0} "
+                      f"compiles, {self.compiles.seconds - s0:.2f}s) — "
+                      f"flagged for controller")
+            if step % self.log_every == 0:
+                print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:.0f} ms")
+            with span(tn.CKPT):
+                stop = bool(self.save and self.save(step, params, opt_state))
+        if self.base is None:
+            self.base = (self.compiles.n, self.compiles.seconds)
+        return params, opt_state, loss, stop
+
+
+def profile_steps(text: str):
+    """'A:B' -> (A, B), the first and last step to trace."""
+    a, b = (int(x) for x in text.split(":"))
+    if not 0 <= a <= b:
+        raise ValueError(f"need 0 <= A <= B, got {text!r}")
+    return a, b
+
+
 def main(argv=None):
     compile_cache.enable_compile_cache()
     ap = argparse.ArgumentParser()
@@ -104,7 +202,16 @@ def main(argv=None):
                          "params/U replicated, grads psum'd exactly "
                          "(docs/distributed.md). Recurrent archs only; "
                          "--batch must divide by the mesh size")
+    ap.add_argument("--profile-dir", default="",
+                    help="write a profiler trace of --profile-steps here "
+                         "(docs/tracing.md)")
+    ap.add_argument("--profile-steps", type=profile_steps, default=None,
+                    help="'A:B': trace steps A to B, both included, with "
+                         "the Python tracer off")
     args = ap.parse_args(argv)
+    prof = args.profile_steps
+    if bool(args.profile_dir) != (prof is not None):
+        ap.error("--profile-dir and --profile-steps go together")
 
     spec = configs.get_arch(args.arch)
     cfg = spec.smoke() if args.smoke else spec.full()
@@ -157,38 +264,50 @@ def main(argv=None):
     if hasattr(cfg, "plan"):
         plan_ran = DropoutPlan.off() if args.no_dropout else cfg.plan
         ckpt_meta = {"dropout_plan": plan_ran.to_dict()}
-    times = []
-    loss = float("nan")   # resume past end of run: no step executes
-    t_train0 = time.time()
-    for step in range(start, args.steps):
-        t0 = time.time()
-        batch = jax.tree.map(jnp.asarray, batch_fn(step))
-        params, opt_state, loss = jitted(
-            params, opt_state, batch, jnp.int32(step),
-            jax.random.fold_in(key, step))
-        loss = float(loss)
-        dt = time.time() - t0
-        times.append(dt)
-        med = float(np.median(times[-50:]))
-        if dt > args.straggler_factor * med and len(times) > 10:
-            print(f"[straggler] step {step} took {dt:.2f}s "
-                  f"(median {med:.2f}s) — flagged for controller")
-        if step % args.log_every == 0:
-            print(f"step {step:5d}  loss {loss:.4f}  {dt*1e3:.0f} ms")
-        do_ckpt = args.ckpt_dir and (
-            (step + 1) % args.ckpt_every == 0 or hook.should_save
-            or step + 1 == args.steps)
-        if do_ckpt:
+
+    def save(step, params, opt_state):
+        if args.ckpt_dir and ((step + 1) % args.ckpt_every == 0
+                              or hook.should_save or step + 1 == args.steps):
             ckpt_mod.save_checkpoint(args.ckpt_dir, step + 1,
                                      (params, opt_state), meta=ckpt_meta)
             if hook.should_save:
                 print(f"[preempt] final checkpoint at step {step+1}; exiting")
-                return 0
+                return True
+        return False
+
+    loss = float("nan")   # resume past end of run: no step executes
+    tracing = False
+    with CompileCounter() as compiles:
+        loop = TrainLoop(jitted, batch_fn, key, compiles=compiles,
+                         straggler_factor=args.straggler_factor,
+                         log_every=args.log_every, save=save)
+        t_train0 = time.time()
+        try:
+            for step in range(start, args.steps):
+                if prof and step == prof[0]:
+                    opts = jax.profiler.ProfileOptions()
+                    opts.python_tracer_level = 0
+                    jax.profiler.start_trace(args.profile_dir,
+                                             create_perfetto_trace=True,
+                                             profiler_options=opts)
+                    tracing = True
+                params, opt_state, loss, stop = loop.step(step, params,
+                                                          opt_state)
+                if tracing and step == prof[1]:
+                    jax.profiler.stop_trace()
+                    tracing = False
+                if stop:
+                    return 0
+        finally:
+            if tracing:
+                jax.profiler.stop_trace()
     total = time.time() - t_train0
     n_run = max(args.steps - start, 0)
+    n_comp, s_comp = loop.compiles_after_first()
     print(f"done: {n_run} steps in {total:.1f}s "
           f"({n_run/max(total,1e-9):.2f} steps/s), "
-          f"final loss {loss:.4f}")
+          f"final loss {loss:.4f}, {n_comp} compiles after the first step "
+          f"({s_comp:.2f}s)")
     return 0
 
 

@@ -25,6 +25,7 @@ from repro.core import lstm as lstm_mod
 from repro.core import metrics
 from repro.core.dropout_plan import DropoutPlan
 from repro.core.sdrop import DropoutSpec
+from repro.launch import trace_names as tn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -101,22 +102,24 @@ def forward(params, tokens, cfg: LSTMLMConfig, *, state=None, ctx=None,
     if ctx is None:
         ctx = cfg.plan.bind(None)
     B, S = tokens.shape
-    x = jnp.take(params["embed"], tokens, axis=0)         # (B,S,E)
-    x = ctx.apply("embed", x)
+    with jax.named_scope(tn.EMBED):
+        x = jnp.take(params["embed"], tokens, axis=0)     # (B,S,E)
+        x = ctx.apply("embed", x).transpose(1, 0, 2)      # (S,B,E)
     if state is None:
         state = lstm_mod.zero_state(cfg.num_layers, B, cfg.hidden)
     ys, state = lstm_mod.lstm_stack(
-        params["lstm"], x.transpose(1, 0, 2), state, ctx=ctx,
-        engine=cfg.engine, lengths=lengths)
-    h = ys.transpose(1, 0, 2)                              # (B,S,H)
-    h = ctx.apply("out", h)
-    if cfg.tie_embeddings:
-        if "proj" in params:
-            h = L.dense(params["proj"], h)
-        logits = jnp.einsum("bsh,vh->bsv", h, params["embed"],
-                            preferred_element_type=jnp.float32)
-    else:
-        logits = L.dense(params["fc"], h).astype(jnp.float32)
+        params["lstm"], x, state, ctx=ctx, engine=cfg.engine,
+        lengths=lengths)
+    with jax.named_scope(tn.OUTPUT):
+        h = ys.transpose(1, 0, 2)                          # (B,S,H)
+        h = ctx.apply("out", h)
+        if cfg.tie_embeddings:
+            if "proj" in params:
+                h = L.dense(params["proj"], h)
+            logits = jnp.einsum("bsh,vh->bsv", h, params["embed"],
+                                preferred_element_type=jnp.float32)
+        else:
+            logits = L.dense(params["fc"], h).astype(jnp.float32)
     return logits, state
 
 
@@ -132,12 +135,13 @@ def loss_fn(params, batch, cfg: LSTMLMConfig, *, state=None, drop_key=None,
     lengths = batch.get("lengths")
     logits, _ = forward(params, batch["tokens"], cfg, state=state, ctx=ctx,
                         lengths=lengths)
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(lp, batch["labels"][..., None], axis=-1)
-    if lengths is None:
-        return nll.mean()
-    mask = metrics.length_mask(lengths, batch["tokens"].shape[1])
-    return metrics.masked_mean(nll[..., 0], mask)
+    with jax.named_scope(tn.LOSS):
+        lp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(lp, batch["labels"][..., None], axis=-1)
+        if lengths is None:
+            return nll.mean()
+        mask = metrics.length_mask(lengths, batch["tokens"].shape[1])
+        return metrics.masked_mean(nll[..., 0], mask)
 
 
 def perplexity(params, tokens, labels, cfg: LSTMLMConfig,

@@ -53,6 +53,7 @@ from repro.core import lstm as lstm_mod
 from repro.core import metrics
 from repro.core.dropout_plan import DropoutPlan
 from repro.core.sdrop import DropoutSpec
+from repro.launch import trace_names as tn
 
 
 @dataclasses.dataclass(frozen=True)
@@ -96,13 +97,14 @@ def encode(params, src, cfg: NMTConfig, *, ctx=None, lengths=None):
     if ctx is None:
         ctx = cfg.plan.bind(None)
     B, S = src.shape
-    x = jnp.take(params["src_embed"], src, axis=0)
+    with jax.named_scope(tn.EMBED):
+        x = jnp.take(params["src_embed"], src, axis=0).transpose(1, 0, 2)
     state = lstm_mod.zero_state(cfg.num_layers, B, cfg.hidden)
     ys, state = lstm_mod.lstm_stack(
-        params["encoder"], x.transpose(1, 0, 2), state, ctx=ctx, site="enc",
+        params["encoder"], x, state, ctx=ctx, site="enc",
         engine=cfg.engine, lengths=lengths)
-    enc = ys.transpose(1, 0, 2)                            # (B,S,H)
-    enc = ctx.apply("enc/out", enc)
+    with jax.named_scope(tn.OUTPUT):
+        enc = ctx.apply("enc/out", ys.transpose(1, 0, 2))  # (B,S,H)
     return enc, state
 
 
@@ -117,11 +119,12 @@ def _scan_site_names(nl):
 
 def _attend(params, cur, enc_proj, enc_out, score_bias):
     """Luong general attention + h~ readout for one step's top state."""
-    scores = jnp.einsum("bh,bsh->bs", cur, enc_proj) + score_bias
-    alpha = jax.nn.softmax(scores, axis=-1)
-    ctx_vec = jnp.einsum("bs,bsh->bh", alpha, enc_out)
-    return jnp.tanh(L.dense(params["w_comb"],
-                            jnp.concatenate([ctx_vec, cur], -1)))
+    with jax.named_scope(tn.ATTENTION):
+        scores = jnp.einsum("bh,bsh->bs", cur, enc_proj) + score_bias
+        alpha = jax.nn.softmax(scores, axis=-1)
+        ctx_vec = jnp.einsum("bs,bsh->bh", alpha, enc_out)
+        return jnp.tanh(L.dense(params["w_comb"],
+                                jnp.concatenate([ctx_vec, cur], -1)))
 
 
 def _dec_step(params, nl, carry, gx0_t, sts, enc_proj, enc_out, score_bias):
@@ -174,12 +177,14 @@ def decode_train(params, tgt_in, enc_out, enc_state, cfg: NMTConfig, *,
     H = cfg.hidden
     nl = cfg.num_layers
     dec = params["decoder"]
-    x = jnp.take(params["tgt_embed"], tgt_in, axis=0)      # (B,St,E)
-    x_seq = x.transpose(1, 0, 2)                           # (St,B,E)
-    enc_proj = L.dense(params["w_att"], enc_out)           # (B,Ss,H)
-    if src_mask is None:
-        src_mask = jnp.ones(enc_out.shape[:2], bool)
-    score_bias = jnp.where(src_mask, 0.0, -1e30).astype(jnp.float32)
+    with jax.named_scope(tn.EMBED):
+        x = jnp.take(params["tgt_embed"], tgt_in, axis=0)  # (B,St,E)
+        x_seq = x.transpose(1, 0, 2)                       # (St,B,E)
+    with jax.named_scope(tn.ATTENTION):
+        enc_proj = L.dense(params["w_att"], enc_out)       # (B,Ss,H)
+        if src_mask is None:
+            src_mask = jnp.ones(enc_out.shape[:2], bool)
+        score_bias = jnp.where(src_mask, 0.0, -1e30).astype(jnp.float32)
     h0, c0 = enc_state.h, enc_state.c
     feed0 = jnp.zeros((B, H), x.dtype)
     site_names = _scan_site_names(nl)
@@ -214,10 +219,11 @@ def decode_train(params, tgt_in, enc_out, enc_state, cfg: NMTConfig, *,
     else:
         # Phase A (both remaining engines): the hoisted embed-half NR
         # matmul, time-batched + compacted at (1-p) FLOPs, bias folded.
-        gx0 = L.dense_sdrop_scheduled(
-            {"w": dec[0]["W"], "b": dec[0]["b"]}, x_seq,
-            ctx.schedule("dec/layer0/nr", St, B, cfg.embed))
-        scheds = [ctx.schedule(n, St, B, H) for n in site_names]
+        with tn.scope(tn.PHASE_A, ctx.spec("dec/layer0/nr").impl):
+            gx0 = L.dense_sdrop_scheduled(
+                {"w": dec[0]["W"], "b": dec[0]["b"]}, x_seq,
+                ctx.schedule("dec/layer0/nr", St, B, cfg.embed))
+            scheds = [ctx.schedule(n, St, B, H) for n in site_names]
         if cfg.engine == "fused":
             from repro.kernels import ops as _kops
             nr0 = ctx.spec("dec/layer0/nr")
@@ -252,9 +258,10 @@ def decode_train(params, tgt_in, enc_out, enc_state, cfg: NMTConfig, *,
             _, h_tildes = jax.lax.scan(step, (h0, c0, feed0),
                                        (gx0, xs_rows, jnp.arange(St)))
     # pass 2: time-batched output dropout + vocab projection.
-    ht = h_tildes.transpose(1, 0, 2)                       # (B,St,H)
-    ht = ctx.apply("dec/out", ht)
-    return L.dense(params["fc"], ht).astype(jnp.float32)
+    with jax.named_scope(tn.OUTPUT):
+        ht = h_tildes.transpose(1, 0, 2)                   # (B,St,H)
+        ht = ctx.apply("dec/out", ht)
+        return L.dense(params["fc"], ht).astype(jnp.float32)
 
 
 def loss_fn(params, batch, cfg: NMTConfig, *, drop_key=None, rules=None,
@@ -280,14 +287,17 @@ def loss_fn(params, batch, cfg: NMTConfig, *, drop_key=None, rules=None,
     logits = decode_train(params, batch["tgt_in"], enc, st, cfg,
                           ctx=ctx, src_mask=src_mask,
                           tgt_lengths=tgt_lengths)
-    lp = jax.nn.log_softmax(logits, axis=-1)
-    nll = -jnp.take_along_axis(lp, batch["tgt_out"][..., None], -1)[..., 0]
-    mask = batch.get("tgt_mask")
-    if mask is None and tgt_lengths is not None:
-        mask = metrics.length_mask(tgt_lengths, batch["tgt_in"].shape[1])
-    if mask is not None:
-        return (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
-    return nll.mean()
+    with jax.named_scope(tn.LOSS):
+        lp = jax.nn.log_softmax(logits, axis=-1)
+        nll = -jnp.take_along_axis(lp, batch["tgt_out"][..., None],
+                                   -1)[..., 0]
+        mask = batch.get("tgt_mask")
+        if mask is None and tgt_lengths is not None:
+            mask = metrics.length_mask(tgt_lengths,
+                                       batch["tgt_in"].shape[1])
+        if mask is not None:
+            return (nll * mask).sum() / jnp.maximum(mask.sum(), 1)
+        return nll.mean()
 
 
 # ---------------------------------------------------------------------------

@@ -12,6 +12,8 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.launch import trace_names as tn
+
 
 class Optimizer(NamedTuple):
     init: Callable
@@ -22,8 +24,10 @@ OptState = Any
 
 
 def apply_updates(params, updates):
-    return jax.tree.map(
-        lambda p, u: (p + u.astype(p.dtype)).astype(p.dtype), params, updates)
+    with jax.named_scope(tn.ADAMW):
+        return jax.tree.map(
+            lambda p, u: (p + u.astype(p.dtype)).astype(p.dtype), params,
+            updates)
 
 
 def global_norm(tree):
@@ -37,9 +41,10 @@ def clip_by_global_norm(max_norm: float) -> Optimizer:
         return ()
 
     def update(grads, state, params=None):
-        g = global_norm(grads)
-        scale = jnp.minimum(1.0, max_norm / jnp.maximum(g, 1e-12))
-        return jax.tree.map(lambda x: x * scale, grads), state
+        with jax.named_scope(tn.GRAD_CLIP):
+            g = global_norm(grads)
+            scale = jnp.minimum(1.0, max_norm / jnp.maximum(g, 1e-12))
+            return jax.tree.map(lambda x: x * scale, grads), state
 
     return Optimizer(init, update)
 
@@ -65,20 +70,21 @@ def adamw(lr, b1=0.9, b2=0.999, eps=1e-8, weight_decay=0.0) -> Optimizer:
                 "step": jnp.zeros((), jnp.int32)}
 
     def update(grads, state, params):
-        step = state["step"] + 1
-        rate = lr(step) if callable(lr) else lr
-        m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g.astype(jnp.float32),
-                         state["m"], grads)
-        v = jax.tree.map(lambda v, g: b2 * v
-                         + (1 - b2) * jnp.square(g.astype(jnp.float32)),
-                         state["v"], grads)
-        mh = jax.tree.map(lambda m: m / (1 - b1 ** step), m)
-        vh = jax.tree.map(lambda v: v / (1 - b2 ** step), v)
-        upd = jax.tree.map(
-            lambda mh, vh, p: -rate * (mh / (jnp.sqrt(vh) + eps)
-                                       + weight_decay * p.astype(jnp.float32)),
-            mh, vh, params)
-        return upd, {"m": m, "v": v, "step": step}
+        with jax.named_scope(tn.ADAMW):
+            step = state["step"] + 1
+            rate = lr(step) if callable(lr) else lr
+            m = jax.tree.map(lambda m, g: b1 * m + (1 - b1) * g.astype(jnp.float32),
+                             state["m"], grads)
+            v = jax.tree.map(lambda v, g: b2 * v
+                             + (1 - b2) * jnp.square(g.astype(jnp.float32)),
+                             state["v"], grads)
+            mh = jax.tree.map(lambda m: m / (1 - b1 ** step), m)
+            vh = jax.tree.map(lambda v: v / (1 - b2 ** step), v)
+            upd = jax.tree.map(
+                lambda mh, vh, p: -rate * (mh / (jnp.sqrt(vh) + eps)
+                                           + weight_decay * p.astype(jnp.float32)),
+                mh, vh, params)
+            return upd, {"m": m, "v": v, "step": step}
 
     return Optimizer(init, update)
 

@@ -123,3 +123,35 @@ def test_gather_matmul_stepped_aligned(one_chip, transpose_b):
             transpose_b=transpose_b, interpret=False),
         a, _spec(one_chip, (K, N)), _spec(one_chip, (T, nk), jnp.int32))
     assert "tpu_custom_call" in text
+
+
+def test_case3_step_keeps_the_benchmark_kernel_names(one_chip, monkeypatch):
+    """The benchmark finds case III's ``lstm_scan`` kernels in the compiled
+    step by op_name (``bench.harness.kernels_missing``) and in the trace
+    by instruction name (the cell's ``kernels.lstm_scan.trace``
+    patterns). A profiler scope around the scan would rename both."""
+    import re
+
+    from bench import harness, traffic
+    from repro.kernels import cell_scan
+
+    # the step is built on a CPU host, where kernels default to interpret
+    monkeypatch.setattr(cell_scan, "resolve_interpret", lambda i: False)
+    jax.clear_caches()
+    cell = harness.load_cell("zaremba-medium.ptb-case3")
+    fn, opt = harness.program_step(cell)
+    params = jax.eval_shape(
+        lambda: harness.init_weights(cell, jax.random.PRNGKey(0)))
+    batch = traffic.make_pool(cell.spec["traffic"], cell.sizes, 7)[0]
+    args = (params, jax.eval_shape(opt.init, params), batch,
+            jax.ShapeDtypeStruct((), jnp.int32),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
+    args = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), args)
+    text = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile().as_text()
+    jax.clear_caches()      # nothing traced for the TPU stays for the CPU
+    kernels = cell.spec["kernels"]
+    assert harness.kernels_missing(text, kernels) == []
+    calls = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    for pattern in kernels["lstm_scan"]["trace"]:
+        assert any(re.match(pattern, c) for c in calls), (pattern, calls)
