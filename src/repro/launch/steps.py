@@ -13,6 +13,7 @@ from typing import Any, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import optim
@@ -96,6 +97,54 @@ def decode_state_shardings(spec: ArchSpec, cfg, state_shapes, mesh, rules):
 # ---------------------------------------------------------------------------
 
 
+def layout_device():
+    """The device whose default layouts the train step keeps."""
+    return jax.devices()[0]
+
+
+def grad_layout_pins(tree):
+    """Which gradient leaves the train step pins to their stored layout.
+
+    -> (pins, n_pinned, nbytes): ``pins`` has, per leaf of ``tree`` (the
+    gradient, or the parameters it mirrors) in flattening order, the
+    device's default layout for the leaf's shape and dtype where that
+    layout is not row-major, else None; ``n_pinned`` and ``nbytes`` count
+    those leaves and their bytes. The leaves may be arrays, tracers or
+    ShapeDtypeStructs. The layout is the whole leaf's, which is its
+    shard's as long as the parameters are replicated."""
+    device = layout_device()
+    pins, n, nbytes = [], 0, 0
+    for x in jax.tree.leaves(tree):
+        lay = None
+        if x.ndim > 1:
+            lay = Layout.from_pjrt_layout(device.client.get_default_layout(
+                x.dtype, x.shape, device))
+            if lay.major_to_minor == tuple(range(x.ndim)):
+                lay = None
+            else:
+                n, nbytes = n + 1, nbytes + x.size * x.dtype.itemsize
+        pins.append(lay)
+    return pins, n, nbytes
+
+
+def pin_grad_layouts(grads):
+    """``grads`` with each leaf constrained to the layout its parameter
+    and AdamW moments are stored in (``grad_layout_pins``).
+
+    The gradient of an embedding lookup comes out of a scatter-add
+    row-major, while a v5e stores a tall, narrow f32 table column-major.
+    Unconstrained, XLA carries the gradient's layout through the
+    elementwise optimizer update and transposes the table and both
+    moments in and out every step; constrained, it transposes the
+    gradient once. On the CPU every default is row-major and nothing is
+    constrained."""
+    leaves, tree = jax.tree.flatten(grads)
+    pins = grad_layout_pins(leaves)[0]
+    return jax.tree.unflatten(tree, [
+        g if lay is None else with_layout_constraint(g, lay)
+        for g, lay in zip(leaves, pins)])
+
+
 def make_train_step(spec: ArchSpec, cfg, opt: optim.optimizers.Optimizer,
                     rules: Optional[shd.ShardingRules], *,
                     n_micro: int = 1, use_dropout: bool = True):
@@ -108,6 +157,7 @@ def make_train_step(spec: ArchSpec, cfg, opt: optim.optimizers.Optimizer,
         loss, grads = grad_fn(params, batch,
                               drop_key=key if use_dropout else None,
                               step=step)
+        grads = pin_grad_layouts(grads)
         updates, opt_state = opt.update(grads, opt_state, params)
         params = optim.apply_updates(params, updates)
         return params, opt_state, loss
@@ -156,6 +206,7 @@ def make_sharded_train_step(spec_or_kind, cfg,
 
     def train_step(params, opt_state, batch, step, key):
         loss, grads = grad_fn(params, batch, step, key)
+        grads = pin_grad_layouts(grads)
         updates, opt_state = opt.update(grads, opt_state, params)
         params = optim.apply_updates(params, updates)
         return params, opt_state, loss

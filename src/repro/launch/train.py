@@ -234,6 +234,10 @@ def main(argv=None):
 
     init_fn, p_shapes, p_shard, _ = steps_mod.param_setup(
         spec, cfg, mesh, rules, seed=args.seed)
+    _, n_pin, pin_bytes = steps_mod.grad_layout_pins(p_shapes)
+    what = ("leaf pinned to its parameter's layout" if n_pin == 1 else
+            "leaves pinned to their parameters' layouts")
+    print(f"[layout] {n_pin} gradient {what} ({pin_bytes / 1e6:.1f} MB)")
     opt = optim.chain(optim.clip_by_global_norm(1.0), optim.adamw(args.lr))
     if args.mesh:
         train_step = steps_mod.make_sharded_train_step(

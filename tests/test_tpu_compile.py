@@ -11,7 +11,9 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 every test file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -125,33 +127,148 @@ def test_gather_matmul_stepped_aligned(one_chip, transpose_b):
     assert "tpu_custom_call" in text
 
 
-def test_case3_step_keeps_the_benchmark_kernel_names(one_chip, monkeypatch):
+# The benchmark's cells (bench/workloads/), each step compiled once for
+# the module by ``cell_step``.
+CELLS = ("zaremba-medium.ptb-case1", "zaremba-medium.ptb-case3",
+         "luong-nmt-iwslt15.envi-case1")
+
+
+@pytest.fixture(scope="module")
+def cell_step(one_chip):
+    """cell name -> (cell, compiled HLO text) of its training step for the
+    described v5e, built as ``bench.harness.program_step`` builds it and
+    jitted with the harness's donation."""
+    from bench import harness, traffic
+    from repro.kernels import cell_scan
+    from repro.launch import steps
+
+    done = {}
+
+    def compile_step(name):
+        if name in done:
+            return done[name]
+        cell = harness.load_cell(name)
+        with pytest.MonkeyPatch.context() as mp:
+            # the step is built on a CPU host, where kernels default to
+            # interpret and layouts are the CPU's
+            mp.setattr(cell_scan, "resolve_interpret", lambda i: False)
+            mp.setattr(steps, "layout_device",
+                       lambda: next(iter(one_chip.device_set)))
+            jax.clear_caches()
+            fn, opt = harness.program_step(cell)
+            params = jax.eval_shape(
+                lambda: harness.init_weights(cell, jax.random.PRNGKey(0)))
+            batch = traffic.make_pool(cell.spec["traffic"], cell.sizes, 7)[0]
+            args = (params, jax.eval_shape(opt.init, params), batch,
+                    jax.ShapeDtypeStruct((), jnp.int32),
+                    jax.ShapeDtypeStruct((2,), jnp.uint32))
+            args = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype),
+                                args)
+            text = jax.jit(fn, donate_argnums=(0, 1)).lower(
+                *args).compile().as_text()
+            jax.clear_caches()  # nothing traced for the TPU stays for the CPU
+        done[name] = cell, text
+        return done[name]
+
+    return compile_step
+
+
+def test_case3_step_keeps_the_benchmark_kernel_names(cell_step):
     """The benchmark finds case III's ``lstm_scan`` kernels in the compiled
     step by op_name (``bench.harness.kernels_missing``) and in the trace
     by instruction name (the cell's ``kernels.lstm_scan.trace``
     patterns). A profiler scope around the scan would rename both."""
-    import re
+    from bench import harness
 
-    from bench import harness, traffic
-    from repro.kernels import cell_scan
-
-    # the step is built on a CPU host, where kernels default to interpret
-    monkeypatch.setattr(cell_scan, "resolve_interpret", lambda i: False)
-    jax.clear_caches()
-    cell = harness.load_cell("zaremba-medium.ptb-case3")
-    fn, opt = harness.program_step(cell)
-    params = jax.eval_shape(
-        lambda: harness.init_weights(cell, jax.random.PRNGKey(0)))
-    batch = traffic.make_pool(cell.spec["traffic"], cell.sizes, 7)[0]
-    args = (params, jax.eval_shape(opt.init, params), batch,
-            jax.ShapeDtypeStruct((), jnp.int32),
-            jax.ShapeDtypeStruct((2,), jnp.uint32))
-    args = jax.tree.map(lambda x: _spec(one_chip, x.shape, x.dtype), args)
-    text = jax.jit(fn, donate_argnums=(0, 1)).lower(*args).compile().as_text()
-    jax.clear_caches()      # nothing traced for the TPU stays for the CPU
+    cell, text = cell_step("zaremba-medium.ptb-case3")
     kernels = cell.spec["kernels"]
     assert harness.kernels_missing(text, kernels) == []
     calls = re.findall(r"^\s*(?:ROOT )?%?([\w.\-]+) = .*"
                        r'custom_call_target="tpu_custom_call"', text, re.M)
     for pattern in kernels["lstm_scan"]["trace"]:
         assert any(re.match(pattern, c) for c in calls), (pattern, calls)
+
+
+def _entry(text):
+    """The entry computation of compiled HLO text: (name -> (opcode,
+    operand names, shape), name of its ROOT)."""
+    body = text[text.index("\nENTRY "):]
+    body = body[body.index("{\n") + 2:body.index("\n}")]
+    insts, root = {}, None
+    for line in body.splitlines():
+        m = re.match(r"\s*(ROOT )?%([\w.\-]+) = (.*?) ([\w\-]+)\((.*)$",
+                     line)
+        if not m:
+            continue
+        is_root, name, shape, op, rest = m.groups()
+        depth = 1
+        for i, ch in enumerate(rest):       # the operands end at the ')'
+            depth += {"(": 1, ")": -1}.get(ch, 0)
+            if not depth:
+                break
+        if 'custom_call_target="ConcatBitcast"' in rest:
+            op = "ConcatBitcast"
+        insts[name] = op, re.findall(r"%([\w.\-]+)", rest[:i]), shape
+        if is_root:
+            root = name
+    return insts, root
+
+
+# ops that move a buffer between memories or re-type it without touching
+# its layout: a copy behind them copies the buffer they were given
+MOVES = ("bitcast", "copy-start", "copy-done", "slice-start", "slice-done",
+         "ConcatBitcast")
+
+
+def _origin(insts, name):
+    while name in insts and insts[name][0] in MOVES:
+        name = insts[name][1][0]
+    return name
+
+
+def _elements(shape):
+    dims = shape.split("[", 1)[1].split("]", 1)[0]
+    return math.prod(int(d) for d in dims.split(",") if d)
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_step_keeps_tables_in_their_stored_layout(cell_step, name):
+    """No parameter or AdamW moment of 1M elements or more is copied, but
+    for the one copy of a table into the layout its gather reads; the
+    update writes every output in the layout it is stored in, so no copy
+    feeds the step's outputs (``steps.pin_grad_layouts``)."""
+    insts, root = _entry(cell_step(name)[1])
+    big = {n for n, (op, _, shape) in insts.items()
+           if op == "parameter" and _elements(shape) >= 1_000_000}
+    fed = {}
+    for n, (op, operands, _) in insts.items():
+        if op == "copy" and _origin(insts, operands[0]) in big:
+            fed.setdefault(_origin(insts, operands[0]), []).append(n)
+    assert not [p for p in fed if not p.startswith("params_")], fed
+    assert all(len(c) == 1 for c in fed.values()), fed
+    outs = [_origin(insts, o) for o in insts[root][1]]
+    assert not [o for o in outs if insts.get(o, ("",))[0] == "copy"], outs
+
+
+def test_grad_layout_pins_on_v5e(one_chip, monkeypatch):
+    """On a v5e the tall embedding table is stored column-major and its
+    gradient is pinned to that layout; the square-ish and wide matrices
+    and the biases are stored row-major and left alone."""
+    from bench import harness
+    from repro.launch import steps
+
+    monkeypatch.setattr(steps, "layout_device",
+                        lambda: next(iter(one_chip.device_set)))
+    cell = harness.load_cell("zaremba-medium.ptb-case1")
+    params = jax.eval_shape(
+        lambda: harness.init_weights(cell, jax.random.PRNGKey(0)))
+    pins, n, nbytes = steps.grad_layout_pins(params)
+    by_name = {jax.tree_util.keystr(p): lay for (p, _), lay in zip(
+        jax.tree_util.tree_flatten_with_path(params)[0], pins)}
+    assert params["embed"].shape == (10000, 650)
+    assert by_name["['embed']"].major_to_minor == (1, 0)    # XLA's {0,1}
+    assert by_name["['lstm'][0]['U']"] is None
+    assert by_name["['fc']['w']"] is None
+    assert [k for k, lay in by_name.items() if lay is not None] == [
+        "['embed']"]
+    assert (n, nbytes) == (1, 10000 * 650 * 4)

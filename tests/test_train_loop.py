@@ -76,6 +76,13 @@ def test_log_line_keeps_its_form(run):
     assert all(float(loss) > 0 for _, loss, _ in rows)
 
 
+def test_layout_line_is_printed_once(run):
+    # on the CPU every default layout is row-major: nothing is pinned
+    lines = re.findall(r"^\[layout\] .*$", run[0], re.M)
+    assert lines == ["[layout] 0 gradient leaves pinned to their "
+                     "parameters' layouts (0.0 MB)"]
+
+
 def test_a_new_batch_shape_counts_one_compile(run):
     out = run[0]
     assert re.search(rf"^\[straggler\] step {RESHAPED} took .*; 1 compiles,",
