@@ -116,7 +116,8 @@ def kept_fn(plan: dict):
 
 def program_config(cell: Cell):
     """(spec, cfg) of the program for this cell: the arch's published
-    config with the cell's sizes, plan and engine."""
+    config with the cell's sizes and plan, and its engine where the cell
+    names one (an arch without a recurrent engine has no such field)."""
     from repro import configs
     from repro.core.dropout_plan import DropoutPlan
     from repro.core.sdrop import DropoutSpec
@@ -127,8 +128,16 @@ def program_config(cell: Cell):
                                block_size=int(s.get("block", 1)),
                                impl=s.get("impl", "xla"))
         for site, s in cell.plan.items()})
-    cfg = dataclasses.replace(spec.full(), **cell.sizes, plan=plan,
-                              engine=cell.spec["engine"])
+    full = spec.full()
+    extra = {}
+    if "engine" in cell.spec:
+        if "engine" not in {f.name for f in dataclasses.fields(full)}:
+            raise ValueError(
+                f"{cell.name}: the cell names engine "
+                f"{cell.spec['engine']!r}, but arch {spec.name!r} "
+                f"({type(full).__name__}) has no 'engine' field")
+        extra["engine"] = cell.spec["engine"]
+    cfg = dataclasses.replace(full, **cell.sizes, plan=plan, **extra)
     return spec, cfg
 
 

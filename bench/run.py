@@ -59,6 +59,12 @@ def _end_to_end(manifest, name, win, setup_s):
             for m in manifest["end_to_end"] if _applies(m, name)}
 
 
+def _scopes(manifest):
+    """The layer names that the manifest's per-layer metrics read."""
+    return {s for m in manifest["per_layer"] for s in getattr(
+        importlib.import_module(f"bench.metrics.{m['name']}"), "SCOPES", ())}
+
+
 def _per_layer(manifest, name, reading):
     out = {}
     for m in manifest["per_layer"]:
@@ -104,15 +110,18 @@ class _Tracer:
 
 
 def _traced_metrics(manifest, name, cell, pool, start, tracer, device,
-                    peak):
+                    peak, hlo):
     """Per-layer metrics of the steps traced from step ``start``, and the
-    breakdown."""
+    breakdown. ``hlo``: the text of each compiled step the window ran."""
     from bench import harness, readers, trace
 
     tracer.stop()
-    devices, spans = trace.load(tracer.dir)
+    devices, spans, modules = trace.load(tracer.dir)
+    scopes = _scopes(manifest)
     red = trace.reduce(devices, spans, {k: v["trace"] for k, v in
-                                        cell.spec["kernels"].items()})
+                                        cell.spec["kernels"].items()},
+                       modules=modules,
+                       hlo=[trace.Hlo(t, scopes) for t in hlo])
     steps = len(tracer.host_ms)
     kept = harness.kept_fn(cell.plan)
     batches = [pool[(start + i) % len(pool)]
@@ -176,13 +185,14 @@ def run(args, *, require_tpu: bool = True, variant: str = "program",
                   "count": len(devs),
                   "memory_peak_bytes": int(stats.get("peak_bytes_in_use",
                                                      0))}
+        hlo = [c.as_text() for c in tr.compiled.values()] if tracer else []
         tr.free()
         del tr
         breakdown = None
         if tracer:
             metrics, breakdown = _traced_metrics(
                 manifest, args.workload, cell, pool, start, tracer, device,
-                peak)
+                peak, hlo)
         else:
             metrics = _end_to_end(manifest, args.workload, win, setup_s)
     finally:
